@@ -40,7 +40,8 @@ single-buffer transport engine (`repro.core.collectives`):
 
 Timing collectives needs >1 device, and XLA device count is fixed at
 process start, so ``run`` re-executes this module as a worker subprocess
-with ``--xla_force_host_platform_device_count=8`` and relays its rows.
+pinned to the CPU with ``--xla_force_host_platform_device_count=8`` and
+relays its rows.
 """
 from __future__ import annotations
 
@@ -60,7 +61,11 @@ _COLLECTIVE = re.compile(
 
 def run(out_dir="results/bench", quick=False):
     env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # the worker measures CPU transport structure: pin it to the CPU so it
+    # never contends for a chip this parent process may hold
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=8").strip()
     env["PYTHONPATH"] = f"{REPO / 'src'}{os.pathsep}{REPO}" + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     cmd = [sys.executable, "-m", "benchmarks.overlap", "--worker"]

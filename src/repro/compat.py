@@ -1,239 +1,91 @@
-"""Cross-version JAX capability / compatibility layer.
+"""The one import point for the JAX APIs this repo builds on (jax 0.9.0).
 
-The repo targets the jax >= 0.6 public API surface but must run on the
-pinned jax 0.4.x toolchain in this container (see docs/COMPAT.md for the
-supported range). Every version-sensitive JAX symbol is resolved HERE,
-once, at import time; no other module in ``src/`` or ``tests/`` may import
-``jax.shard_map`` / ``jax.sharding.AxisType`` / ``jax.tree.leaves_with_path``
-directly. Consumers do::
+Every module takes ``shard_map``, mesh construction, named-axis queries,
+the pytree helpers and the scheduling fence from here, so a future JAX
+upgrade touches one file; ``tests/test_compat.py`` fails the suite on a
+direct import of the guarded symbols elsewhere.  Consumers do::
 
     from repro.compat import shard_map, make_mesh, tree_map, ...
 
 Exports
-  shard_map               jax.shard_map -> jax.experimental.shard_map
-                          fallback; translates check_vma <-> check_rep.
-  make_mesh               jax.make_mesh with axis_types when the installed
-                          version supports it, without when it doesn't,
-                          and a manual Mesh() fallback for very old jax.
-  HAS_AXIS_TYPES / axis_type_auto
-                          AxisType capability detection.
+  shard_map               ``jax.shard_map`` (bare or as a decorator).
+  make_mesh               ``jax.make_mesh`` with every axis ``AxisType.Auto``.
+  axis_size               ``jax.lax.axis_size``.
   tree_map / tree_leaves / tree_flatten / tree_unflatten /
   tree_structure / tree_leaves_with_path / tree_map_with_path / keystr
-                          jax.tree.* when present, jax.tree_util.* shims
-                          otherwise (jax.tree.leaves_with_path only landed
-                          after 0.4.x).
-  HAS_FP8 / FLOAT8_E4M3 / FLOAT8_E5M2 / has_dtype
-                          FP8 wire-format capability detection.
-  optimization_barrier / HAS_OPTIMIZATION_BARRIER
-                          jax.lax.optimization_barrier where available
-                          (the scheduling fence of the software-pipelined
-                          ring transport), identity fallback otherwise —
-                          results are bit-identical either way, only the
-                          anti-reordering fence is lost.
+                          ``jax.tree.*`` and ``jax.tree_util.keystr``.
+  FLOAT8_E4M3 / FLOAT8_E5M2
+                          the fp8 wire dtypes.
+  optimization_barrier    forward-only scheduling fence (see its docstring).
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
 import jax.numpy as jnp
-from jax import tree_util as _tu
 
 __all__ = [
-    "JAX_VERSION", "shard_map", "make_mesh", "HAS_AXIS_TYPES",
-    "axis_type_auto", "axis_size", "tree_map", "tree_leaves",
+    "shard_map", "make_mesh", "axis_size", "tree_map", "tree_leaves",
     "tree_flatten", "tree_unflatten", "tree_structure",
-    "tree_leaves_with_path", "tree_map_with_path", "keystr", "HAS_FP8",
-    "FLOAT8_E4M3", "FLOAT8_E5M2", "has_dtype", "optimization_barrier",
-    "HAS_OPTIMIZATION_BARRIER",
+    "tree_leaves_with_path", "tree_map_with_path", "keystr",
+    "FLOAT8_E4M3", "FLOAT8_E5M2", "optimization_barrier",
 ]
 
-
-def _parse_version(v: str) -> tuple:
-    parts = []
-    for p in v.split(".")[:3]:
-        digits = "".join(ch for ch in p if ch.isdigit())
-        parts.append(int(digits) if digits else 0)
-    return tuple(parts)
-
-
-JAX_VERSION: tuple = _parse_version(jax.__version__)
-
-
-# --------------------------------------------------------------------------
-# shard_map
-# --------------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):                      # jax >= 0.6
-    _native_shard_map = jax.shard_map
-else:                                              # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _native_shard_map
-
-_SM_PARAMS = frozenset(inspect.signature(_native_shard_map).parameters)
-
-
-def shard_map(f=None, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-    """Version-portable ``shard_map``.
-
-    Accepts the modern keyword ``check_vma``; on versions whose native
-    shard_map only knows ``check_rep`` (same meaning, older name) the flag
-    is renamed before the call. Usable bare or as a decorator factory
-    (``shard_map(mesh=..., ...)(f)``), like the native one.
-    """
-    def bind(fn):
-        kw = dict(kwargs)
-        if check_vma is not None:
-            if "check_vma" in _SM_PARAMS:
-                kw["check_vma"] = check_vma
-            elif "check_rep" in _SM_PARAMS:
-                kw["check_rep"] = check_vma
-        return _native_shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, **kw)
-
-    return bind if f is None else bind(f)
-
-
-# --------------------------------------------------------------------------
-# mesh construction
-# --------------------------------------------------------------------------
-
-HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-_MAKE_MESH_PARAMS = (
-    frozenset(inspect.signature(jax.make_mesh).parameters)
-    if hasattr(jax, "make_mesh") else frozenset())
-
-
-def axis_type_auto():
-    """``AxisType.Auto`` on versions that have it, else None (meshes are
-    implicitly Auto there — it was the only behaviour)."""
-    return jax.sharding.AxisType.Auto if HAS_AXIS_TYPES else None
+shard_map = jax.shard_map
+axis_size = jax.lax.axis_size
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None, axis_types=None):
-    """``jax.make_mesh`` that always produces Auto-typed axes.
-
-    On jax versions with ``AxisType`` the mesh is constructed explicitly
-    Auto (silences the v0.9 axis_types default-change warning); on versions
-    without it the kwarg is dropped — 0.4.x meshes carry no axis types.
-    """
-    axis_shapes = tuple(int(s) for s in axis_shapes)
+    """``jax.make_mesh`` whose axes default to ``AxisType.Auto`` (the
+    sharding-propagation semantics every shard_map here assumes)."""
     axis_names = tuple(axis_names)
-    kw = {}
-    if devices is not None:
-        kw["devices"] = devices
-    if HAS_AXIS_TYPES and "axis_types" in _MAKE_MESH_PARAMS:
-        if axis_types is None:
-            axis_types = (axis_type_auto(),) * len(axis_names)
-        kw["axis_types"] = axis_types
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(axis_shapes, axis_names, **kw)
-    # pre-make_mesh fallback: reshape the flat device list by hand
-    import numpy as np
-    n = 1
-    for s in axis_shapes:
-        n *= s
-    devs = np.asarray(devices if devices is not None
-                      else jax.devices()[:n]).reshape(axis_shapes)
-    return jax.sharding.Mesh(devs, axis_names)
+    if axis_types is None:
+        axis_types = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(tuple(int(s) for s in axis_shapes), axis_names,
+                         axis_types=axis_types, **kw)
 
 
-# --------------------------------------------------------------------------
-# named-axis queries inside shard_map
-# --------------------------------------------------------------------------
-
-if hasattr(jax.lax, "axis_size"):                  # jax >= 0.6
-
-    def axis_size(axis_name) -> int:
-        """Static size of a named mesh axis (inside shard_map)."""
-        return jax.lax.axis_size(axis_name)
-
-else:
-
-    def axis_size(axis_name) -> int:
-        """Static size of a named mesh axis (inside shard_map).
-
-        Pre-``lax.axis_size`` idiom: ``psum`` of the constant 1 over the
-        axis constant-folds to the axis size as a Python int."""
-        return jax.lax.psum(1, axis_name)
+@jax.custom_vjp
+def _barrier(values):
+    return jax.lax.optimization_barrier(values)
 
 
-# --------------------------------------------------------------------------
-# scheduling fences
-# --------------------------------------------------------------------------
-
-HAS_OPTIMIZATION_BARRIER = hasattr(jax.lax, "optimization_barrier")
-
-if HAS_OPTIMIZATION_BARRIER:
-
-    @jax.custom_vjp
-    def _barrier(values):
-        return jax.lax.optimization_barrier(values)
-
-    def _barrier_fwd(values):
-        return jax.lax.optimization_barrier(values), None
-
-    def _barrier_bwd(_, ct):
-        # The barrier is semantically the identity, so its cotangent is a
-        # pass-through.  No fence on the backward: reverse-mode emission
-        # order is the autodiff engine's business, not the scheduler's.
-        return (ct,)
-
-    _barrier.defvjp(_barrier_fwd, _barrier_bwd)
-
-    def optimization_barrier(values):
-        """Identity on ``values`` (any pytree) that XLA may not reorder
-        across: every op producing an input finishes before any op
-        consuming an output starts.  The software-pipelined ring transport
-        (``repro.core.overlap``) fences its stage ticks with this so the
-        compiler cannot re-serialize the interleaved chunk streams.
-
-        Differentiable: some installed versions define no AD rule for the
-        underlying primitive, yet the fenced ring runs under
-        ``value_and_grad`` when it carries workloads directly (the
-        ring-attention KV hops) rather than sitting inside a
-        ``custom_vjp`` collective — so the fence is wrapped in a
-        straight-through ``custom_vjp`` (forward fences, backward passes
-        cotangents through unchanged)."""
-        return _barrier(values)
-
-else:
-
-    def optimization_barrier(values):
-        """Identity fallback for jax builds without
-        ``lax.optimization_barrier``: results are bit-identical (the
-        barrier is semantically the identity), only the anti-reordering
-        scheduling fence is lost."""
-        return values
+def _barrier_fwd(values):
+    return jax.lax.optimization_barrier(values), None
 
 
-# --------------------------------------------------------------------------
-# pytree shims (jax.tree.* grew over several 0.4.x releases)
-# --------------------------------------------------------------------------
-
-def _tree_fn(name: str, tu_name: str):
-    t = getattr(jax, "tree", None)
-    fn = getattr(t, name, None) if t is not None else None
-    return fn if fn is not None else getattr(_tu, tu_name)
+def _barrier_bwd(_, ct):
+    # The barrier is semantically the identity, so its cotangent is a
+    # pass-through.  No fence on the backward: reverse-mode emission
+    # order is the autodiff engine's business, not the scheduler's.
+    return (ct,)
 
 
-tree_map = _tree_fn("map", "tree_map")
-tree_leaves = _tree_fn("leaves", "tree_leaves")
-tree_flatten = _tree_fn("flatten", "tree_flatten")
-tree_unflatten = _tree_fn("unflatten", "tree_unflatten")
-tree_structure = _tree_fn("structure", "tree_structure")
-tree_leaves_with_path = _tree_fn("leaves_with_path", "tree_leaves_with_path")
-tree_map_with_path = _tree_fn("map_with_path", "tree_map_with_path")
-keystr = _tu.keystr
+_barrier.defvjp(_barrier_fwd, _barrier_bwd)
 
 
-# --------------------------------------------------------------------------
-# dtype / feature detection
-# --------------------------------------------------------------------------
+def optimization_barrier(values):
+    """Identity on ``values`` (any pytree) that XLA may not reorder
+    across: every op producing an input finishes before any op consuming
+    an output starts.  The software-pipelined ring transport
+    (``repro.core.overlap``) fences its stage ticks with this so the
+    compiler cannot re-serialize the interleaved chunk streams.
 
-def has_dtype(name: str) -> bool:
-    return getattr(jnp, name, None) is not None
+    Straight-through under autodiff (a ``custom_vjp``): the forward is
+    fenced, the backward passes cotangents through unchanged, so the
+    ring-attention KV hops that run under ``value_and_grad`` add no
+    fences to the backward pass."""
+    return _barrier(values)
 
 
-FLOAT8_E4M3 = getattr(jnp, "float8_e4m3fn", None)
-FLOAT8_E5M2 = getattr(jnp, "float8_e5m2", None)
-HAS_FP8 = FLOAT8_E4M3 is not None and FLOAT8_E5M2 is not None
+tree_map = jax.tree.map
+tree_leaves = jax.tree.leaves
+tree_flatten = jax.tree.flatten
+tree_unflatten = jax.tree.unflatten
+tree_structure = jax.tree.structure
+tree_leaves_with_path = jax.tree.leaves_with_path
+tree_map_with_path = jax.tree.map_with_path
+keystr = jax.tree_util.keystr
+
+FLOAT8_E4M3 = jnp.float8_e4m3fn
+FLOAT8_E5M2 = jnp.float8_e5m2

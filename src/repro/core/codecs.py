@@ -312,10 +312,11 @@ class IdentityCodec:
 class TacoCodec(WireFastPath):
     """The paper's compressor. Payload uint8 (bitcast fp8/int8) + scales.
 
-    On the Pallas impls the wire-native methods dispatch to the fused
+    On the interpret impl the wire-native methods dispatch to the fused
     kernels (``kernels.ash_compress.compress_wire_pallas`` and friends)
     that read/write the packed uint8 buffer at its static
-    ``wire_layout(n)`` byte offsets directly — no pack/unpack copies."""
+    ``wire_layout(n)`` byte offsets directly — no pack/unpack copies.
+    The compiled TPU impl runs the block kernels + ``pack_wire``."""
 
     cfg: TacoConfig = TacoConfig()
     chunks: int = 1
@@ -387,14 +388,15 @@ class TacoCodec(WireFastPath):
         scalars = groups + (0 if self.cfg.metadata == "folded" else 1)
         return 1.0 + 4.0 * scalars / b
 
-    # ---- fused wire-native fast paths (Pallas impls, VMEM-sized slots) ----
+    # ---- fused wire-native fast paths (interpret mode; see
+    # kernels.ops.wire_kernel_impl for why the TPU impl packs instead) ----
     def encode_wire(self, x):
-        if kops.wire_kernel_impl(self.cfg, x.shape[-1]) is not None:
+        if kops.wire_kernel_impl(self.cfg) is not None:
             return kops.compress_wire(x, self.cfg)
         return super().encode_wire(x)
 
     def decode_wire(self, wire, n, dtype):
-        if kops.wire_kernel_impl(self.cfg, n) is not None:
+        if kops.wire_kernel_impl(self.cfg) is not None:
             lead = wire.shape[:-1]
             out = kops.decompress_wire(
                 wire.reshape(-1, wire.shape[-1]), n, self.cfg)
@@ -402,12 +404,9 @@ class TacoCodec(WireFastPath):
         return super().decode_wire(wire, n, dtype)
 
     def decode_sum_wire(self, wire, n, dtype):
-        # the fused reduce kernel consumes a (P, total_bytes) peer stack
-        # as ONE Pallas block, so the VMEM budget is gated on P*n (not n);
+        # the fused reduce kernel consumes a (P, total_bytes) peer stack;
         # other stackings take the generic unpack path
-        if wire.ndim == 2 and \
-                kops.wire_kernel_impl(self.cfg, wire.shape[0] * n) \
-                is not None:
+        if wire.ndim == 2 and kops.wire_kernel_impl(self.cfg) is not None:
             out = kops.decompress_reduce_wire(wire, n, self.cfg)
             return out.reshape(-1)[:n].astype(dtype)
         return super().decode_sum_wire(wire, n, dtype)

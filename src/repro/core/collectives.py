@@ -29,9 +29,11 @@ per component (2–3 before).  The buffer is produced/consumed through the
 codec's wire-native fast paths (``encode_wire``/``decode_wire``/
 ``decode_sum_wire``): the generic codecs compose ``pack_wire``/
 ``unpack_wire`` (bitcast + concat, defined in ``repro.core.codecs`` and
-re-exported here), while TACO's Pallas impls emit and read the packed
-bytes straight from the fused kernels — no concat-and-slice copies
-between compression and the collective.  ``multibuffer_wire()`` restores
+re-exported here), while TACO's interpret impl emits and reads the
+packed bytes straight from the fused kernels — no concat-and-slice
+copies between compression and the collective (on TPU the fused wire
+kernels do not compile, so TACO packs there too; see
+``repro.kernels.ops.wire_kernel_impl``).  ``multibuffer_wire()`` restores
 the per-component transport for parity tests and benchmarks.
 
 Bounded-but-ragged slots: hybrid stacks (``taco+zle`` — see
@@ -268,7 +270,7 @@ def _transport(x2d, codec, move, *, reduce=False, dtype):
     """Shared codec plumbing for every compressed collective: pad the
     trailing dim of ``x2d`` to the codec granule, encode straight into the
     packed uint8 wire buffer (``encode_wire`` — one fused kernel write on
-    the Pallas impls), apply ``move`` (ONE lax collective), and decode
+    the interpret impl), apply ``move`` (ONE lax collective), and decode
     straight from the moved buffer — fused-summing the stacked peer axis
     when ``reduce`` — then crop the padding.  Codecs without a wire
     layout (or under :func:`multibuffer_wire`) fall back to one ``move``

@@ -41,28 +41,15 @@ class FormatSpec:
         return jnp.uint8 if self.is_float else jnp.int8
 
 
-# FP8 entries only exist when the installed jax/ml_dtypes expose the
-# dtypes (compat feature detection) — the paper's §6 graceful-degradation
-# path for non-FP8 stacks is the int8 format, which is always present.
 FORMATS: dict[str, FormatSpec] = {
     "int8": FormatSpec("int8", jnp.int8, 127.0, False),
+    "e4m3": FormatSpec("e4m3", compat.FLOAT8_E4M3, 448.0, True),
+    "e5m2": FormatSpec("e5m2", compat.FLOAT8_E5M2, 57344.0, True),
 }
-if compat.HAS_FP8:
-    FORMATS["e4m3"] = FormatSpec("e4m3", compat.FLOAT8_E4M3, 448.0, True)
-    FORMATS["e5m2"] = FormatSpec("e5m2", compat.FLOAT8_E5M2, 57344.0, True)
 
 
 def get_format(name: str) -> FormatSpec:
-    """FORMATS lookup with an actionable error on non-FP8 stacks."""
-    try:
-        return FORMATS[name]
-    except KeyError:
-        if name in ("e4m3", "e5m2") and not compat.HAS_FP8:
-            raise RuntimeError(
-                f"FP8 format {name!r} requested but this jax/ml_dtypes "
-                "stack exposes no float8 dtypes; use fmt='int8' (the paper "
-                "§6 graceful-degradation path)") from None
-        raise
+    return FORMATS[name]
 
 
 def _group(z: jax.Array, group_size: int) -> jax.Array:

@@ -31,6 +31,13 @@ from repro.core import ash as ash_mod
 ROW_TILE = 128
 
 
+def rotate(x, h):
+    """``x @ h`` contracted in f32 on the MXU.  XLA's TPU default rounds
+    f32 dot operands to bf16; the kernels state their precision so their
+    on-chip output matches the f32 reference (``kernels/ref.py``)."""
+    return jnp.dot(x, h, precision=jax.lax.Precision.HIGHEST)
+
+
 def _block_compress(g, h, *, tau, eps, scale_eps, qmax, groups, out_dtype,
                     is_float):
     """Shared per-block-row math of both compress kernels: (R, B) f32 ->
@@ -38,18 +45,22 @@ def _block_compress(g, h, *, tau, eps, scale_eps, qmax, groups, out_dtype,
     independent, and both kernels invoke it at the same (ROW_TILE, B)
     tile shape (see ``_row_tiles``), so the block and fused-wire paths
     produce bit-identical rows — the wire fast path's parity contract."""
-    r, b = g.shape
+    b = g.shape[-1]
     # -- reduction 1: block RMS energy ------------------------------------
     sigma = jnp.sqrt(jnp.mean(g * g, axis=-1) + eps)        # (R,)
     alpha = tau / sigma                                     # (R,)
     # -- rotation on the MXU ----------------------------------------------
-    z = (alpha[:, None] * g) @ h                            # (R, B)
+    z = rotate(alpha[:, None] * g, h)                       # (R, B)
     # -- reduction 2: per-group max magnitude ------------------------------
-    zg = z.reshape(r, groups, b // groups)
-    s = jnp.max(jnp.abs(zg), axis=-1) / qmax                # (R, G)
-    s = jnp.maximum(s, scale_eps)   # cfg.scale_eps — same floor as the ref
+    # over static lane slices: Mosaic refuses the (R, B) -> (R, G, B/G)
+    # reshape of a grouped reduction
+    gs = b // groups
+    amax = jnp.concatenate(
+        [jnp.max(jnp.abs(z[:, k * gs:(k + 1) * gs]), axis=-1, keepdims=True)
+         for k in range(groups)], axis=-1)                  # (R, G)
+    s = jnp.maximum(amax / qmax, scale_eps)   # cfg.scale_eps, as in the ref
     # -- saturating convert -------------------------------------------------
-    scaled = jnp.clip(zg / s[..., None], -qmax, qmax).reshape(r, b)
+    scaled = jnp.clip(z / jnp.repeat(s, gs, axis=-1), -qmax, qmax)
     if is_float:
         q = scaled.astype(out_dtype)
     else:
@@ -64,7 +75,7 @@ def _compress_kernel(x_ref, h_ref, q_ref, alpha_ref, s_ref, *, tau, eps,
         g, h_ref[...], tau=tau, eps=eps, scale_eps=scale_eps, qmax=qmax,
         groups=groups, out_dtype=out_dtype, is_float=is_float)
     q_ref[...] = q
-    alpha_ref[...] = alpha
+    alpha_ref[...] = alpha[:, None]
     s_ref[...] = s
 
 
@@ -105,16 +116,17 @@ def compress_blocks_pallas(blocks: jax.Array, cfg, interpret: bool = False):
         ],
         out_specs=[
             pl.BlockSpec((ROW_TILE, b), lambda i: (i, 0)),
-            pl.BlockSpec((ROW_TILE,), lambda i: (i,)),
+            pl.BlockSpec((ROW_TILE, 1), lambda i: (i, 0)),
             pl.BlockSpec((ROW_TILE, groups), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((mp, b), fmt.dtype),
-            jax.ShapeDtypeStruct((mp,), jnp.float32),
+            jax.ShapeDtypeStruct((mp, 1), jnp.float32),
             jax.ShapeDtypeStruct((mp, groups), jnp.float32),
         ],
         interpret=interpret,
     )(blocks, h)
+    alpha = alpha.reshape(mp)
     if mp != m:
         q, alpha, s = q[:m], alpha[:m], s[:m]
     return q, alpha, s

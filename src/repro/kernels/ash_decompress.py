@@ -25,7 +25,7 @@ from repro.core import ash as ash_mod
 # by it, and the tile-shape bit-parity contract (see ash_compress.
 # _row_tiles) requires every kernel to matmul at the same (ROW_TILE, B)
 from repro.kernels.ash_compress import (ROW_TILE, _pad_rows, _row_tiles,
-                                        wire_geometry)
+                                        rotate, wire_geometry)
 
 
 def _expand_scale(s, r, b, groups):
@@ -38,10 +38,10 @@ def _decompress_kernel(q_ref, s_ref, alpha_ref, h_ref, o_ref, *, groups,
     r, b = q.shape
     z = q * _expand_scale(s_ref[...], r, b, groups)
     if apply_rotation:
-        g = z @ h_ref[...]
+        g = rotate(z, h_ref[...])
     else:
         g = z
-    g = g / alpha_ref[...][:, None]
+    g = g / alpha_ref[...]
     o_ref[...] = g.astype(out_dtype)
 
 
@@ -71,13 +71,13 @@ def decompress_blocks_pallas(q, s, alpha, cfg, interpret: bool = False):
         in_specs=[
             pl.BlockSpec((ROW_TILE, b), lambda i: (i, 0)),
             pl.BlockSpec((ROW_TILE, groups), lambda i: (i, 0)),
-            pl.BlockSpec((ROW_TILE,), lambda i: (i,)),
+            pl.BlockSpec((ROW_TILE, 1), lambda i: (i, 0)),
             pl.BlockSpec((b, b), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((ROW_TILE, b), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, b), cfg.compute_dtype),
         interpret=interpret,
-    )(q, s, alpha, h)
+    )(q, s, alpha.reshape(mp, 1), h)
     return out[:m] if mp != m else out
 
 
@@ -89,7 +89,7 @@ def _decompress_reduce_kernel(q_ref, f_ref, h_ref, o_ref, *, groups,
     fe = jnp.repeat(f, b // groups, axis=-1).reshape(p, r, b)
     acc = jnp.sum(q * fe, axis=0)                           # rotated-domain sum
     if apply_rotation:
-        acc = acc @ h_ref[...]                              # ONE inverse rotation
+        acc = rotate(acc, h_ref[...])                       # ONE inverse rotation
     o_ref[...] = acc.astype(out_dtype)
 
 
@@ -160,7 +160,7 @@ def _decompress_wire_kernel(w_ref, h_ref, o_ref, *, mb, b, groups, folded,
         qt = _pad_rows(q[r0:r0 + rows].astype(jnp.float32), ROW_TILE)
         st = _pad_rows(s[r0:r0 + rows].reshape(rows, groups), ROW_TILE)
         z = qt * _expand_scale(st, ROW_TILE, b, groups)
-        g = z @ h_ref[...] if apply_rotation else z
+        g = rotate(z, h_ref[...]) if apply_rotation else z
         if not folded:   # folded metadata already carries s/alpha
             at = _pad_rows(alpha[r0:r0 + rows], ROW_TILE, value=1.0)
             g = g / at[:, None]
@@ -224,7 +224,7 @@ def _decompress_reduce_wire_kernel(w_ref, h_ref, o_ref, *, mb, b, groups,
         fe = jnp.repeat(ft, b // groups, axis=-1).reshape(p, ROW_TILE, b)
         acc = jnp.sum(qt * fe, axis=0)                      # rotated domain
         if apply_rotation:
-            acc = acc @ h_ref[...]                          # ONE inverse rot
+            acc = rotate(acc, h_ref[...])                   # ONE inverse rot
         o_ref[r0:r0 + rows, :] = acc[:rows].astype(out_dtype)
 
 

@@ -17,22 +17,25 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 ROW_TILE = 128
 
 
 def _fwht_body(x):
-    """In-register butterfly over the last axis (power of 2)."""
-    lead, n = x.shape[:-1], x.shape[-1]
-    y = x.reshape(-1, n)
+    """In-register butterfly over the last axis (power of 2).  Stage ``h``
+    pairs lane ``i`` with lane ``i ^ h``; the partner is fetched with a
+    lane rotation (Mosaic refuses the reshape-based pairing)."""
+    n = x.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
     h = 1
     while h < n:
-        y = y.reshape(-1, n // (2 * h), 2, h)
-        a = y[:, :, 0, :]
-        b = y[:, :, 1, :]
-        y = jnp.concatenate([a + b, a - b], axis=-1)
+        upper = (lane & h) != 0
+        partner = jnp.where(upper, pltpu.roll(x, h, x.ndim - 1),
+                            pltpu.roll(x, n - h, x.ndim - 1))
+        x = jnp.where(upper, partner - x, x + partner)
         h *= 2
-    return y.reshape(*lead, n)
+    return x
 
 
 def _compress_kernel(x_ref, q_ref, alpha_ref, s_ref, *, tau, eps, qmax,
@@ -45,8 +48,8 @@ def _compress_kernel(x_ref, q_ref, alpha_ref, s_ref, *, tau, eps, qmax,
     scaled = jnp.clip(z / s[:, None], -qmax, qmax)
     q_ref[...] = scaled.astype(out_dtype) if is_float else \
         jnp.round(scaled).astype(jnp.int8)
-    alpha_ref[...] = alpha
-    s_ref[...] = s
+    alpha_ref[...] = alpha[:, None]
+    s_ref[...] = s[:, None]
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "interpret"))
@@ -68,19 +71,20 @@ def compress_blocks_butterfly(blocks: jax.Array, cfg, interpret: bool = False):
         in_specs=[pl.BlockSpec((ROW_TILE, b), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((ROW_TILE, b), lambda i: (i, 0)),
-            pl.BlockSpec((ROW_TILE,), lambda i: (i,)),
-            pl.BlockSpec((ROW_TILE,), lambda i: (i,)),
+            pl.BlockSpec((ROW_TILE, 1), lambda i: (i, 0)),
+            pl.BlockSpec((ROW_TILE, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((mp, b), fmt.dtype),
-            jax.ShapeDtypeStruct((mp,), jnp.float32),
-            jax.ShapeDtypeStruct((mp,), jnp.float32),
+            jax.ShapeDtypeStruct((mp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((mp, 1), jnp.float32),
         ],
         interpret=interpret,
     )(blocks)
+    alpha = alpha.reshape(mp)
     if mp != m:
         q, alpha, s = q[:m], alpha[:m], s[:m]
-    return q, alpha, s[:, None]
+    return q, alpha, s
 
 
 def flops_per_element(b: int) -> dict:

@@ -14,9 +14,18 @@ from repro.kernels import ash_compress, ash_decompress, ref
 
 
 def _impl_for(cfg) -> str:
+    """The impl that runs ``cfg``.  ``auto`` takes the jnp reference for
+    the ablation configs the kernels do not implement; an explicit kernel
+    impl on such a config is an error, never a silent swap."""
     impl = cfg.resolved_impl()
     if impl in ("pallas", "pallas_interpret") and not ash_compress.supported(cfg):
-        return "jnp"
+        if cfg.impl == "auto":
+            return "jnp"
+        raise ValueError(
+            f"impl={cfg.impl!r} has no kernel for transform="
+            f"{cfg.transform!r}, scale_granularity={cfg.scale_granularity!r}"
+            " (the kernels implement transform='ash' with block scales); "
+            "use impl='auto' or 'jnp'")
     return impl
 
 
@@ -72,44 +81,30 @@ def decompress_reduce(q: jax.Array, s: jax.Array, alpha, cfg):
 # codec composes pack_wire/unpack_wire with encode/decode instead)
 # --------------------------------------------------------------------------
 
-# VMEM guard for the on-device fused wire path: the wire kernels hold one
-# whole transport slot per Pallas block (grid over slots), so a huge
-# monolithic slot — e.g. a full flattened gradient all-gather — would
-# neither fit VMEM nor trace cheaply (the in-kernel ROW_TILE loop unrolls
-# mb/128 matmuls).  Slots past this budget fall back to the ROW_TILE-tiled
-# block kernels + pack_wire.  Interpret mode has no VMEM and stays fused
-# at any size (CPU parity tests and benchmarks).
-WIRE_FUSED_MAX_SLOT_ELEMS = 512 * 1024   # ~2 MB f32 in + ~0.5 MB wire out
-
-
-def wire_kernel_impl(cfg, n: int | None = None):
-    """The Pallas impl name when the fused wire kernels cover ``cfg`` at
-    slot size ``n`` (same config coverage as the block kernels, plus the
-    on-device VMEM slot budget), else None."""
+# The fused wire kernels run in interpret mode only.  Compiled for TPU
+# (v5e), Mosaic refuses them: the one-slot (1, n) blocks break the
+# (8, 128) block rule, and serializing f32 metadata into the uint8 row
+# needs an in-kernel bitcast that changes bit width ("Changing bitwidths
+# not supported").  So on the device every hop takes the block kernels
+# + pack_wire, at any slot size; tests/test_wire_fused.py pins the rule.
+def wire_kernel_impl(cfg):
+    """``"pallas_interpret"`` when the fused wire kernels run ``cfg``,
+    else None (jnp, and the compiled TPU impl — see above)."""
     impl = _impl_for(cfg)
-    if impl not in ("pallas", "pallas_interpret"):
-        return None
-    if impl == "pallas" and n is not None and n > WIRE_FUSED_MAX_SLOT_ELEMS:
-        return None
-    return impl
+    return impl if impl == "pallas_interpret" else None
 
 
 def compress_wire(x: jax.Array, cfg):
     """(slots, n) -> packed (slots, total_bytes) uint8 wire buffer."""
-    impl = wire_kernel_impl(cfg, x.shape[-1])
-    return ash_compress.compress_wire_pallas(
-        x, cfg, interpret=(impl == "pallas_interpret"))
+    return ash_compress.compress_wire_pallas(x, cfg, interpret=True)
 
 
 def decompress_wire(wire: jax.Array, n: int, cfg):
     """Packed (slots, total_bytes) uint8 -> (slots, n) compute dtype."""
-    impl = wire_kernel_impl(cfg, n)
-    return ash_decompress.decompress_wire_pallas(
-        wire, n, cfg, interpret=(impl == "pallas_interpret"))
+    return ash_decompress.decompress_wire_pallas(wire, n, cfg, interpret=True)
 
 
 def decompress_reduce_wire(wire: jax.Array, n: int, cfg):
     """Peer-stacked (P, total_bytes) wire rows -> fused summed (mb, B)."""
-    impl = wire_kernel_impl(cfg, n)
     return ash_decompress.decompress_reduce_wire_pallas(
-        wire, n, cfg, interpret=(impl == "pallas_interpret"))
+        wire, n, cfg, interpret=True)

@@ -1,5 +1,10 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# A CPU rehearsal tool: pin it to the CPU backend (it must never take a
+# chip) and append the 512-device placeholder world to the caller's flags.
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=512"
+                           ).strip()
 
 """Multi-pod dry-run (deliverable e) + roofline extraction (deliverable g).
 
@@ -7,9 +12,10 @@ Meshes (spec-mandated, built by launch/mesh.py):
   single-pod : (16, 16)      ("data", "model")        256 chips
   multi-pod  : (2, 16, 16)   ("pod", "data", "model") 512 chips
 
-The 512-device placeholder world is forced by the XLA_FLAGS line ABOVE ALL
+The 512-device placeholder world is forced by the XLA_FLAGS lines ABOVE ALL
 IMPORTS (jax locks the device count on first init; nothing else in the
-repo sets this globally — smoke tests and benches see 1 device).
+repo sets this globally — smoke tests and benches see 1 device), which
+also pin the process to the CPU backend.
 
 Modes:
   --mode check     lower+compile the production config (scan-over-layers,

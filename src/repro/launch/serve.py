@@ -27,6 +27,7 @@ from repro.configs import get_config, make_plan, smoke_config
 from repro.core.parallel import ParallelCtx
 from repro.core.registry import from_spec, to_spec
 from repro.launch._args import add_policy_alias, resolve_comm_spec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh, mesh_axis_info
 from repro.models.model import Model
 from repro.serve.engine import ServeEngine
@@ -102,6 +103,7 @@ def main():
                     help="restore params from a checkpoint dir")
     ap.add_argument("--kv", default="auto", choices=["auto", "pad_shard"])
     args = ap.parse_args()
+    enable_compile_cache()
 
     shape = tuple(int(x) for x in args.mesh.split(","))
     mesh = make_mesh(shape, ("pod", "data", "model"))

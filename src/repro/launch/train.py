@@ -1,11 +1,14 @@
 """Production training launcher.
 
 On real hardware this runs under `python -m repro.launch.train` on every
-host of the pod slice (jax.distributed handles cross-host init); in this
-container it drives the same code path on small meshes.
+host of the pod slice (jax.distributed handles cross-host init); on a CPU
+it drives the same code path on small meshes.
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b \
         --smoke --steps 50 --comm-spec "tp=taco,warmup=10"
+
+``build_parser`` + ``build_trainer`` are the programmatic form of the same
+entry point (``chip_smoke.py`` drives them).
 """
 from __future__ import annotations
 
@@ -14,9 +17,10 @@ import logging
 
 from repro.configs import get_config, make_plan, smoke_config
 from repro.core.parallel import ParallelCtx
-from repro.core.registry import from_spec, to_spec
+from repro.core.registry import from_spec
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.launch._args import add_policy_alias, resolve_comm_spec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import (SP_AXIS, make_mesh, mesh_axis_info,
                                sp_axis_info)
 from repro.models.model import Model
@@ -24,7 +28,7 @@ from repro.optim.adamw import OptConfig
 from repro.train.trainer import Trainer, TrainerConfig
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gpt-350m")
     ap.add_argument("--smoke", action="store_true",
@@ -53,12 +57,18 @@ def main():
                          "pipelined; see docs/COMPRESSION.md)")
     add_policy_alias(ap)
     ap.add_argument("--lr", type=float, default=3e-4)
-    ap.add_argument("--ckpt", default="/tmp/repro_train_ckpt")
-    ap.add_argument("--resume", action="store_true", default=True)
-    args = ap.parse_args()
+    ap.add_argument("--ckpt", default="/tmp/repro_train_ckpt",
+                    help="checkpoint directory ('' for no checkpoints)")
+    ap.add_argument("--resume", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="resume from the latest checkpoint in --ckpt "
+                         "(--no-resume starts fresh)")
+    return ap
 
-    logging.basicConfig(level=logging.INFO,
-                        format="%(asctime)s %(name)s %(message)s")
+
+def build_trainer(args, cfg=None) -> Trainer:
+    """The Trainer ``main`` runs for parsed ``args``; ``cfg`` overrides the
+    ``--arch``/``--smoke`` architecture (e.g. a depth-cut config)."""
     shape = tuple(int(x) for x in args.mesh.split(","))
     axes = ("pod", "data", "model")
     if args.sp > 1:
@@ -71,9 +81,10 @@ def main():
     fsdp_axes, tp_axis, tp, fsdp = mesh_axis_info(mesh)
     sp_axis, sp = sp_axis_info(mesh)
 
-    cfg = get_config(args.arch)
-    if args.smoke:
-        cfg = smoke_config(cfg)
+    if cfg is None:
+        cfg = get_config(args.arch)
+        if args.smoke:
+            cfg = smoke_config(cfg)
     plan = make_plan(cfg, tp, fsdp)
     model = Model(cfg, plan, fsdp_axes=fsdp_axes, tp_axis=tp_axis,
                   sp_axis=sp_axis)
@@ -91,11 +102,20 @@ def main():
                    total_steps=args.steps)
     tc = TrainerConfig(total_steps=args.steps,
                        ckpt_every=max(args.steps // 4, 10),
-                       log_every=10, ckpt_dir=args.ckpt)
-    trainer = Trainer(model, mesh, ctx, oc, tc, data)
+                       log_every=10, ckpt_dir=args.ckpt or None)
+    return Trainer(model, mesh, ctx, oc, tc, data)
+
+
+def main():
+    args = build_parser().parse_args()
+    logging.basicConfig(level=logging.INFO,
+                        format="%(asctime)s %(name)s %(message)s")
+    enable_compile_cache()
+    trainer = build_trainer(args)
     _, _, losses = trainer.run(resume=args.resume)
-    print(f"{cfg.name}: loss {losses[0]:.4f} -> {losses[-1]:.4f} "
-          f"({len(losses)} steps, comm_spec={to_spec(comm_plan)})")
+    print(f"{trainer.model.cfg.name}: loss {losses[0]:.4f} -> "
+          f"{losses[-1]:.4f} ({len(losses)} steps, "
+          f"comm_spec={trainer.comm_spec})")
 
 
 if __name__ == "__main__":

@@ -49,7 +49,7 @@ class TrainerConfig:
     total_steps: int = 100
     ckpt_every: int = 50
     log_every: int = 10
-    ckpt_dir: str = "/tmp/repro_ckpt"
+    ckpt_dir: str | None = "/tmp/repro_ckpt"   # None: no checkpoints
     keep_last: int = 3
     seed: int = 0
 
@@ -103,16 +103,27 @@ class Trainer:
 
     # ---- state ------------------------------------------------------------
     def init_state(self):
+        """Fresh (params, opt_state, 0), initialised under jit straight
+        into their target shardings: no device ever holds more than its
+        shard (an eager init would build the whole model on device 0)."""
         from jax.sharding import NamedSharding
-        params = self.model.init(jax.random.PRNGKey(self.tc.seed))
         pspecs = self.model.partition_specs()
-        params = compat.tree_map(
-            lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
-            params, pspecs)
-        opt_state = adamw.init_opt_state(params)
+        shard = lambda s: NamedSharding(self.mesh, s)  # noqa: E731
+        out_shardings = (compat.tree_map(shard, pspecs),
+                         compat.tree_map(shard,
+                                         adamw.opt_state_pspecs(pspecs)))
+
+        def init(key):
+            params = self.model.init(key)
+            return params, adamw.init_opt_state(params)
+
+        params, opt_state = jax.jit(init, out_shardings=out_shardings)(
+            jax.random.PRNGKey(self.tc.seed))
         return params, opt_state, 0
 
     def try_restore(self, params_tmpl, opt_tmpl):
+        if self.tc.ckpt_dir is None:
+            return None
         step = ckpt.latest_step(self.tc.ckpt_dir)
         if step is None:
             return None
@@ -135,6 +146,7 @@ class Trainer:
 
         retry = RetryPolicy()
         step = start
+        stepped = False   # has any step completed in this process?
         bspecs = self.model.batch_pspecs()
         while step < self.tc.total_steps:
             try:
@@ -169,13 +181,19 @@ class Trainer:
                              float(metrics["lr"]), dt,
                              metrics["comm/tp_fwd_bytes_per_elem"])
                 step += 1
-                if step % self.tc.ckpt_every == 0 or step == self.tc.total_steps:
+                stepped = True
+                if self.tc.ckpt_dir is not None and (
+                        step % self.tc.ckpt_every == 0
+                        or step == self.tc.total_steps):
                     ckpt.save(self.tc.ckpt_dir, step,
                               {"params": params, "opt": opt_state},
                               keep_last=self.tc.keep_last,
                               comm_spec=self.comm_spec)
             except Exception as exc:  # noqa: BLE001 — restart boundary
-                if not retry.should_retry(exc):
+                # before the first completed step a failure is a build
+                # fault (compile error, device out of memory): rebuilding
+                # cannot fix it, so it surfaces at once
+                if not stepped or not retry.should_retry(exc):
                     raise
                 params, opt_state, start = self.init_state()
                 restored = self.try_restore(params, opt_state)

@@ -54,7 +54,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import HAS_OPTIMIZATION_BARRIER, shard_map
+from repro.compat import shard_map
 from repro.core import collectives as cc
 from repro.core.codecs import (IdentityCodec, Int8Codec, Sdp4BitCodec,
                                TacoCodec, TahQuantCodec)
@@ -391,13 +391,10 @@ for sched, txt in (("pipelined", txt_pipe), ("serial", txt_ser)):
     if sched == "pipelined":
         # at least the steady-state encodes (chunks 2..N-1) land between
         # ring steps, every tick is fenced, and fences sit between steps
-        # (on builds without lax.optimization_barrier the compat fence is
-        # the identity: interleaved emission order still holds, barriers
-        # are absent by design)
-        want_bar = CHUNKS + 2 if HAS_OPTIMIZATION_BARRIER else 0
+        want_bar = CHUNKS + 2
         check_true("hlo/ag_ring_pipelined_interleaves_encodes",
                    enc_mid >= CHUNKS - 2 and len(bar) == want_bar
-                   and (bar_mid >= 1 or not HAS_OPTIMIZATION_BARRIER),
+                   and bar_mid >= 1,
                    f"encodes_between_permutes={enc_mid} "
                    f"barriers={len(bar)} (want {want_bar}) "
                    f"barriers_between_permutes={bar_mid}")

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import HAS_OPTIMIZATION_BARRIER, make_mesh, shard_map
+from repro.compat import make_mesh, shard_map
 from repro.configs import get_config, make_plan, smoke_config
 from repro.core.parallel import CommPlan, ParallelCtx
 from repro.core.registry import codec_from_spec, from_spec
@@ -188,10 +188,10 @@ for label, codec in (("pipelined", TACO), ("serial", TACO_SERIAL),
     else:
         # pipelined: (sp-1) ring ticks + 2 = fences; steady-state block
         # partials land between the permutes
-        want_bar = (SP - 1) + 2 if HAS_OPTIMIZATION_BARRIER else 0
+        want_bar = (SP - 1) + 2
         check_true(f"hlo/ring_{label}_pipelined_interleaves_partials",
                    exp_mid >= 1 and len(bar) == want_bar
-                   and (bar_mid >= 1 or not HAS_OPTIMIZATION_BARRIER),
+                   and bar_mid >= 1,
                    f"exps_between_permutes={exp_mid} "
                    f"barriers={len(bar)} (want {want_bar}) "
                    f"barriers_between_permutes={bar_mid}")

@@ -76,3 +76,22 @@ def test_one_multipod_cell_compiles():
         cwd=str(REPO))
     assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
     assert "0 errors" in proc.stdout
+
+
+def test_dryrun_appends_flags_and_pins_cpu():
+    """The dry-run is a CPU rehearsal tool: importing it keeps the
+    caller's XLA_FLAGS (appending its 512-device world) and pins JAX to
+    the CPU even where no platform was chosen."""
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    env["XLA_FLAGS"] = "--xla_cpu_enable_fast_math=false"
+    env["PYTHONPATH"] = str(REPO / "src")
+    code = ("import os, jax, repro.launch.dryrun; "
+            "print(os.environ['XLA_FLAGS']); "
+            "print(jax.devices()[0].platform, len(jax.devices()))")
+    proc = subprocess.run([sys.executable, "-c", code], env=env, cwd=REPO,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    flags, devices = proc.stdout.strip().splitlines()[-2:]
+    assert flags.startswith("--xla_cpu_enable_fast_math=false ")
+    assert "--xla_force_host_platform_device_count=512" in flags
+    assert devices == "cpu 512"

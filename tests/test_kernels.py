@@ -139,13 +139,22 @@ def test_scale_floor_routed_through_wire_kernel():
                                   np.asarray(want))
 
 
-def test_kernel_fallback_for_unsupported_config(rng):
-    """Ablation configs (plain hadamard / per-tensor scale) fall back to the
-    jnp path even when pallas requested."""
+def test_kernel_fallback_for_unsupported_config(rng, monkeypatch):
+    """Ablation configs (plain hadamard / per-tensor scale) take the jnp
+    path only under impl='auto'; an explicit kernel impl raises instead
+    of silently running the reference."""
     x = jnp.asarray(tp_like(rng, (4, 256)))
-    cfg = TacoConfig(transform="hadamard", impl="pallas_interpret")
-    q, a, s = ops.compress_blocks(x, cfg)  # must not raise
-    assert q.shape == (4, 256)
+    for kw in (dict(transform="hadamard"), dict(scale_granularity="tensor")):
+        for impl in ("pallas", "pallas_interpret"):
+            with pytest.raises(ValueError, match="has no kernel"):
+                ops.compress_blocks(x, TacoConfig(impl=impl, **kw))
+        q, a, s = ops.compress_blocks(x, TacoConfig(impl="auto", **kw))
+        assert q.shape == (4, 256)
+    # the auto rule as a TPU host sees it: kernels for the production
+    # config, the reference for ablations
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._impl_for(TacoConfig()) == "pallas"
+    assert ops._impl_for(TacoConfig(transform="hadamard")) == "jnp"
 
 
 def test_end_to_end_error_tiny_vs_direct_cast(rng):

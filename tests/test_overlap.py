@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import HAS_OPTIMIZATION_BARRIER, shard_map
+from repro.compat import shard_map
 from repro.core import collectives as cc
 from repro.core import overlap
 from repro.core.codecs import IdentityCodec, TacoCodec
@@ -423,9 +423,6 @@ def test_ring_schedule_reads_the_codec_knob():
         overlap.ring_schedule(dataclasses.replace(TACO, schedule="bogus"))
 
 
-@pytest.mark.skipif(
-    not HAS_OPTIMIZATION_BARRIER,
-    reason="no lax.optimization_barrier: compat fence is the identity")
 def test_hlo_pipelined_ring_fences_serial_ring_does_not(rng):
     """The pipelined schedule emits one optimization_barrier per tick
     (chunks + 2 of them); the serial schedule emits none.  (The encode/

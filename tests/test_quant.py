@@ -7,26 +7,13 @@ try:
 except ImportError:  # offline container — seeded-sampling shim
     from _hypothesis_compat import given, settings, strategies as st
 
-from repro import compat
 from repro.core import quant
 from repro.core.taco import TacoConfig, compress, decompress, wire_bytes, raw_bytes
 
 from conftest import tp_like
 
-# the library degrades to int8 on non-FP8 stacks (docs/COMPAT.md); the
-# FP8-specific cells skip there instead of KeyError-ing
-requires_fp8 = pytest.mark.skipif(
-    not compat.HAS_FP8, reason="FP8 dtypes unavailable on this jax stack")
-
-
-def _skip_unless_available(fmt):
-    if fmt != "int8" and not compat.HAS_FP8:
-        pytest.skip(f"format {fmt} needs FP8 dtypes")
-
-
 @pytest.mark.parametrize("fmt", ["e4m3", "e5m2", "int8"])
 def test_quantize_within_range(fmt, rng):
-    _skip_unless_available(fmt)
     spec = quant.FORMATS[fmt]
     z = jnp.asarray(tp_like(rng, (16, 256)))
     q, s = quant.quantize_ds(z, spec)
@@ -39,7 +26,6 @@ def test_quantize_within_range(fmt, rng):
 @pytest.mark.parametrize("fmt", ["e4m3", "e5m2", "int8"])
 @pytest.mark.parametrize("gs", [32, 64, 256])
 def test_roundtrip_error_bounded(fmt, gs, rng):
-    _skip_unless_available(fmt)
     spec = quant.FORMATS[fmt]
     z = jnp.asarray(rng.normal(0, 1.0, (8, 256)).astype(np.float32))
     q, s = quant.quantize_ds(z, spec, group_size=gs)
@@ -50,7 +36,6 @@ def test_roundtrip_error_bounded(fmt, gs, rng):
     assert np.all(np.abs(zh - np.asarray(z)) <= smax * step + 1e-7)
 
 
-@requires_fp8
 def test_zero_tensor_stable():
     cfg = TacoConfig(impl="jnp")
     x = jnp.zeros((4, 256), jnp.float32)
@@ -60,7 +45,6 @@ def test_zero_tensor_stable():
     np.testing.assert_allclose(np.asarray(xh), 0.0, atol=1e-6)
 
 
-@requires_fp8
 def test_fp8_beats_int8_on_near_zero_heavy_tail(rng):
     """Paper §3 core claim: for zero-concentrated long-tail data WITHOUT
     pre-conditioning, FP8's exponential grid loses far less of the dense
@@ -79,7 +63,6 @@ def test_fp8_beats_int8_on_near_zero_heavy_tail(rng):
     assert errs["e4m3"] < errs["int8"]
 
 
-@requires_fp8
 def test_compression_ratio(rng):
     x = jnp.asarray(tp_like(rng, (1024, 1024)))  # bf16-sized payloads in prod
     for meta, lo in [("dual", 3.7), ("folded", 3.8)]:
@@ -90,7 +73,6 @@ def test_compression_ratio(rng):
         assert ratio > lo / 2, (meta, ratio)
 
 
-@requires_fp8
 def test_folded_metadata_bit_identical(rng):
     """DESIGN.md §7.1: alpha cancels when s is max-based at block-or-finer
     granularity — folded single-scale metadata reconstructs identically."""
@@ -104,7 +86,6 @@ def test_folded_metadata_bit_identical(rng):
                                    rtol=1e-4, atol=1e-5)
 
 
-@requires_fp8
 @settings(max_examples=25, deadline=None)
 @given(
     seed=st.integers(0, 2**31 - 1),

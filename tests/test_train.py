@@ -90,3 +90,50 @@ def test_restart_after_injected_failure(tmp_path):
 
     for a, b in zip(jax.tree.leaves(p_ref), jax.tree.leaves(p_failed)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_failure_before_first_step_is_not_retried(tmp_path):
+    """A failure before any step completed (compile error, device OOM)
+    surfaces at once: rebuilding cannot fix it, so nothing is retried."""
+    model, ctx, oc, tc, data = small_setup(tmp_path, "baseline",
+                                           total_steps=3)
+    injector = FailureInjector(fail_at_steps=[0])
+    tr = Trainer(model, mesh1(), ctx, oc, tc, data, injector=injector)
+    with pytest.raises(RuntimeError, match="injected node failure"):
+        tr.run(resume=False)
+    assert tr.losses == [] and injector.fired == {0}
+
+
+def test_init_state_is_sharded_and_matches_eager_init(tmp_path):
+    """init_state builds params and optimizer state under jit straight
+    into their target shardings, with the values an eager init gives (up
+    to one bf16 rounding step where jit fuses the init scale into the
+    cast), and an f32 master copy equal to the params."""
+    from jax.sharding import NamedSharding
+    model, ctx, oc, tc, data = small_setup(tmp_path, "baseline")
+    tr = Trainer(model, mesh1(), ctx, oc, tc, data)
+    params, opt, step = tr.init_state()
+    assert step == 0
+    eager = model.init(jax.random.PRNGKey(tc.seed))
+    specs = model.partition_specs()
+    for got, want, spec in zip(jax.tree.leaves(params),
+                               jax.tree.leaves(eager),
+                               jax.tree.leaves(specs)):
+        assert got.sharding == NamedSharding(mesh1(), spec)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=2.0 ** -7, atol=0)
+    for got, want in zip(jax.tree.leaves(opt["master"]),
+                         jax.tree.leaves(params)):
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(want, np.float32))
+
+
+def test_no_checkpoint_dir_writes_nothing(tmp_path):
+    model, ctx, oc, tc, data = small_setup(tmp_path, "baseline",
+                                           total_steps=2)
+    import dataclasses
+    tc = dataclasses.replace(tc, ckpt_dir=None)
+    tr = Trainer(model, mesh1(), ctx, oc, tc, data)
+    _, _, losses = tr.run(resume=True)
+    assert len(losses) == 2 and list(tmp_path.iterdir()) == []

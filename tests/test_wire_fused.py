@@ -106,33 +106,24 @@ def test_fused_wire_width_matches_layout_contract(rng):
 
 
 def test_on_device_fused_path_has_a_vmem_slot_budget():
-    """impl=pallas (real TPU) falls back to the ROW_TILE-tiled block
-    kernels + pack_wire for slots past the VMEM budget (the wire kernels
-    hold one slot per Pallas block); interpret mode stays fused at any
-    size so the CPU parity/bench coverage is unbounded."""
+    """impl=pallas (real TPU) takes the block kernels + pack_wire at every
+    slot size: Mosaic refuses the fused wire kernels, so their on-device
+    VMEM budget is zero.  Interpret mode stays fused at any size so the
+    CPU parity/bench coverage is unbounded; jnp never fuses."""
     from repro.kernels import ops as kops
-    cfg_hw = codec_from_spec("taco:pallas").cfg
-    cfg_it = FUSED.cfg
-    small, huge = 4096, kops.WIRE_FUSED_MAX_SLOT_ELEMS + 256
-    assert kops.wire_kernel_impl(cfg_hw, small) == "pallas"
-    assert kops.wire_kernel_impl(cfg_hw, huge) is None
-    assert kops.wire_kernel_impl(cfg_it, huge) == "pallas_interpret"
-    assert kops.wire_kernel_impl(codec_from_spec("taco:jnp").cfg,
-                                 small) is None
-    # the fused reduce kernel holds the whole (P, total) peer stack as
-    # one block, so decode_sum_wire must gate the budget on peers*n, not
-    # n alone — capture the element count it asks wire_kernel_impl about
-    x = jnp.zeros((1, 512), jnp.float32)
+    assert kops.wire_kernel_impl(codec_from_spec("taco:pallas").cfg) is None
+    assert kops.wire_kernel_impl(
+        codec_from_spec("taco:pallas:folded").cfg) is None
+    assert kops.wire_kernel_impl(FUSED.cfg) == "pallas_interpret"
+    assert kops.wire_kernel_impl(codec_from_spec("taco:jnp").cfg) is None
+    # the interpret-mode reduce kernel holds the whole (P, total) peer
+    # stack, at any P: three peers decode-sum through it to 3x one peer
+    x = jnp.ones((1, 512), jnp.float32)
+    one = FUSED.decode_sum_wire(FUSED.encode_wire(x), 512, jnp.float32)
     stack = jnp.concatenate([FUSED.encode_wire(x)] * 3)   # (3, total)
-    seen = []
-    orig = kops.wire_kernel_impl
-    try:
-        kops.wire_kernel_impl = \
-            lambda cfg, m=None: seen.append(m) or orig(cfg, m)
-        FUSED.decode_sum_wire(stack, 512, jnp.float32)
-    finally:
-        kops.wire_kernel_impl = orig
-    assert seen[0] == 3 * 512, seen
+    three = FUSED.decode_sum_wire(stack, 512, jnp.float32)
+    np.testing.assert_allclose(np.asarray(three), 3 * np.asarray(one),
+                               rtol=1e-6)
 
 
 def test_identity_codec_has_no_wire_form():
