@@ -69,6 +69,7 @@ import numpy as np
 
 from repro.core import dp_compress, pp_compress
 from repro.core.overlap import PIPELINED
+from repro.core.telemetry import SCOPE_WIRE
 from repro.core.taco import TacoConfig
 from repro.kernels import ops as kops
 
@@ -191,6 +192,7 @@ def _from_bytes(seg, dtype, size):
     return jax.lax.bitcast_convert_type(seg, dt)
 
 
+@jax.named_scope(SCOPE_WIRE)
 def pack_wire(enc, layout):
     """Encoded component tuple -> ONE contiguous uint8 buffer per slot,
     laid out per ``layout`` (bitcast + trailing-axis concatenation).
@@ -212,6 +214,7 @@ def pack_wire(enc, layout):
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-1)
 
 
+@jax.named_scope(SCOPE_WIRE)
 def unpack_wire(wire, layout):
     """Inverse of :func:`pack_wire`: slice the uint8 buffer at the static
     byte offsets and bitcast each component back.  Works with any number

@@ -98,6 +98,7 @@ import jax.numpy as jnp
 
 from repro.compat import axis_size
 from repro.core import overlap
+from repro.core.telemetry import SCOPE_DECODE, SCOPE_ENCODE, SCOPE_MOVE
 from repro.core.codecs import (IdentityCodec,  # noqa: F401 — re-exported
                                achieved_wire_bytes, pack_wire, unpack_wire)
 
@@ -285,22 +286,30 @@ def _transport(x2d, codec, move, *, reduce=False, dtype):
     pn = padded.shape[-1]
     layout = _wire_layout(codec, pn) if _WIRE_PACKING.get() else None
     if layout is None:
-        enc = tuple(move(a) for a in codec.encode(padded))
-        if reduce:
-            return codec.decode_sum(enc, pn, dtype)[:n]
-        return codec.decode(enc, pn, dtype)[..., :n]
-    wire = codec.encode_wire(padded)
+        with jax.named_scope(SCOPE_ENCODE):
+            enc = codec.encode(padded)
+        with jax.named_scope(SCOPE_MOVE):
+            enc = tuple(move(a) for a in enc)
+        with jax.named_scope(SCOPE_DECODE):
+            if reduce:
+                return codec.decode_sum(enc, pn, dtype)[:n]
+            return codec.decode(enc, pn, dtype)[..., :n]
     moved_b = negotiated_wire_bytes(codec, pn, chunk=None)
-    _slot_probe(codec, layout, wire,
-                layout.total_bytes if moved_b is None else moved_b, 0)
-    _err_probe(codec, padded, wire, pn)
-    if moved_b is not None and moved_b < layout.total_bytes:
-        wire = _zero_repad(move(wire[..., :moved_b]), layout.total_bytes)
-    else:
-        wire = move(wire)
-    if reduce:
-        return codec.decode_sum_wire(wire, pn, dtype)[:n]
-    return codec.decode_wire(wire, pn, dtype)[..., :n]
+    with jax.named_scope(SCOPE_ENCODE):
+        wire = codec.encode_wire(padded)
+        _slot_probe(codec, layout, wire,
+                    layout.total_bytes if moved_b is None else moved_b, 0)
+        _err_probe(codec, padded, wire, pn)
+    with jax.named_scope(SCOPE_MOVE):
+        if moved_b is not None and moved_b < layout.total_bytes:
+            wire = _zero_repad(move(wire[..., :moved_b]),
+                               layout.total_bytes)
+        else:
+            wire = move(wire)
+    with jax.named_scope(SCOPE_DECODE):
+        if reduce:
+            return codec.decode_sum_wire(wire, pn, dtype)[:n]
+        return codec.decode_wire(wire, pn, dtype)[..., :n]
 
 
 def _compressed_collective(name, impl, bwd, n_static, doc=None):
@@ -401,6 +410,7 @@ def _ag_one_ring(x, ax, dim, codec):
     ring = tuple((s, (s + 1) % p) for s in range(p))
     idx = jax.lax.axis_index(ax)
 
+    @jax.named_scope(SCOPE_MOVE)
     def transfer(buf):
         """P-1 neighbor-forwarding ring steps -> peer-ordered stack."""
         arrivals = [buf]
@@ -410,6 +420,7 @@ def _ag_one_ring(x, ax, dim, codec):
         return _peer_order(jnp.stack(arrivals)[:, 0], idx, p)   # (P, bytes)
 
     def enc_for(c):
+        @jax.named_scope(SCOPE_ENCODE)
         def enc(seg):
             wire = codec.encode_wire(seg)
             m = moved[c]
@@ -420,6 +431,7 @@ def _ag_one_ring(x, ax, dim, codec):
         return enc
 
     def dec_for(c):
+        @jax.named_scope(SCOPE_DECODE)
         def dec(stack):
             if moved[c] is not None and moved[c] < total:
                 stack = _zero_repad(stack, total)
@@ -472,6 +484,7 @@ def _rs_one_ring(x, ax, dim, codec):
              for c in range(len(segs))]
     idx = jax.lax.axis_index(ax)
 
+    @jax.named_scope(SCOPE_MOVE)
     def transfer(wire):
         """Shifted two-shot sends -> peer-ordered stack, one hoisted
         gather: ``sends[k] == wire[(idx + k) % p]``."""
@@ -483,6 +496,7 @@ def _rs_one_ring(x, ax, dim, codec):
         return _peer_order(jnp.stack(arrivals), idx, p)        # (P, bytes)
 
     def enc_for(c):
+        @jax.named_scope(SCOPE_ENCODE)
         def enc(seg):
             wire = codec.encode_wire(seg)
             m = moved[c]
@@ -493,6 +507,7 @@ def _rs_one_ring(x, ax, dim, codec):
         return enc
 
     def dec_for(c):
+        @jax.named_scope(SCOPE_DECODE)
         def dec(stack):
             if moved[c] is not None and moved[c] < total:
                 stack = _zero_repad(stack, total)

@@ -4,12 +4,18 @@ One reporter abstraction feeds both consumers (the ROADMAP's adaptive
 compression controller wants a single stats stream to train its policy
 on):
 
-  * the TRAINER merges :func:`comm_metrics` — the static per-path wire
-    accounting of the plan that actually ran a step — into its metrics
-    dict every step (``comm/*`` keys);
+  * the TRAINER logs :func:`comm_metrics` — the static per-path wire
+    accounting of the plan that actually ran a step — on its log steps
+    (``comm/*`` keys);
   * the SERVING ENGINE emits per-request latency rows (``serve/request``
     events: queue wait, prefill time, per-token decode time, achieved
     wire bytes) and engine counters through a :class:`Reporter`.
+
+The training loop's host phases are profiler spans (:class:`StepPhases`,
+names below), and :class:`StepProfile` captures a profiler trace of a
+few steps plus the compiled step's HLO text, whose ``op_name`` metadata
+carries the step's named scopes (``taco/*``, ``attn``, ``mlp``, ``head``,
+``optim``) for the device ops of the trace.
 
 Everything here is host-side Python on static plan data — the only
 device work is the one cached probe encode behind
@@ -18,8 +24,126 @@ device work is the one cached probe encode behind
 from __future__ import annotations
 
 import collections
+import contextlib
 import logging
+import os
 import time
+
+import jax
+
+# --------------------------------------------------------------------------
+# training-loop spans (jax.profiler TraceMe names; always on: with no
+# profiler running a span records nothing)
+# --------------------------------------------------------------------------
+
+#: One loop iteration, a step marker carrying ``step_num``.
+STEP_SPAN = "train"
+#: The data source's ``batch(step)``: host rows for the step.
+SPAN_DATA = "train/data"
+#: ``place``: the rows' ``device_put`` onto the mesh.
+SPAN_PLACE = "train/place"
+#: Plan resolution and the compiled step's enqueue; returns before the
+#: device finishes.
+SPAN_DISPATCH = "train/dispatch"
+#: The blocking read of the step's loss: the host waits for the device.
+SPAN_SYNC = "train/sync"
+#: Straggler watchdog, telemetry and the log line.
+SPAN_LOG = "train/log"
+#: Checkpoint save.
+SPAN_CKPT = "train/ckpt"
+
+
+# --------------------------------------------------------------------------
+# named scopes of the compiled step (jax.named_scope: op_name metadata only,
+# the compiled program is otherwise unchanged); rematerialised scopes appear
+# again under the backward pass's prefix
+# --------------------------------------------------------------------------
+
+#: A compressed hop's encode into its wire buffer (``_transport``).
+SCOPE_ENCODE = "taco/encode"
+#: The hop's one lax collective on the wire buffer.
+SCOPE_MOVE = "taco/move"
+#: The decode (or fused decode-and-sum) of the moved wire buffer.
+SCOPE_DECODE = "taco/decode"
+#: ``pack_wire``/``unpack_wire``: the components' byte relayout.
+SCOPE_WIRE = "taco/wire"
+#: Attention: projections, core and output projection.
+SCOPE_ATTN = "attn"
+#: The feed-forward block.
+SCOPE_MLP = "mlp"
+#: Embedding, final norm, output head and loss.
+SCOPE_HEAD = "head"
+#: Gradient finalisation and the AdamW update.
+SCOPE_OPTIM = "optim"
+STEP_SCOPES = (SCOPE_ENCODE, SCOPE_MOVE, SCOPE_DECODE, SCOPE_WIRE,
+               SCOPE_ATTN, SCOPE_MLP, SCOPE_HEAD, SCOPE_OPTIM)
+
+
+class StepPhases:
+    """Host phases of one training step: each is a profiler span and a
+    ``time.perf_counter`` duration, kept until the next step begins."""
+
+    def __init__(self):
+        self.ms: dict[str, float] = {}
+        self._open: tuple[str, float] | None = None
+
+    def step(self, step: int):
+        """The iteration's step span; clears the previous step's split."""
+        self.ms = {}
+        return jax.profiler.StepTraceAnnotation(STEP_SPAN, step_num=step)
+
+    @contextlib.contextmanager
+    def phase(self, name: str):
+        short = name.rpartition("/")[2]
+        t0 = time.perf_counter()
+        self._open = (short, t0)
+        try:
+            with jax.profiler.TraceAnnotation(name):
+                yield
+        finally:
+            self._open = None
+            self.ms[short] = (time.perf_counter() - t0) * 1e3
+
+    def split(self) -> dict[str, float]:
+        """Milliseconds per phase of this step so far, the open phase's
+        up to now."""
+        out = dict(self.ms)
+        if self._open is not None:
+            short, t0 = self._open
+            out[short] = (time.perf_counter() - t0) * 1e3
+        return out
+
+
+class StepProfile:
+    """A profiler trace of steps ``[first, first + count)`` written to
+    ``out_dir``, and beside it ``step.hlo.txt``: the compiled step's HLO
+    text, whose ``op_name`` metadata names the scope of every device op
+    in the trace."""
+
+    HLO_FILE = "step.hlo.txt"
+
+    def __init__(self, out_dir: str, first: int, count: int):
+        self.out_dir, self.first, self.count = out_dir, first, count
+        self.active = self.done = False
+
+    def before(self, step: int) -> None:
+        if not (self.active or self.done) \
+                and self.first <= step < self.first + self.count:
+            jax.profiler.start_trace(self.out_dir)
+            self.active = True
+
+    def after(self, next_step: int, step_hlo) -> None:
+        """Stop once the last profiled step has run; ``step_hlo()``
+        returns the compiled step's HLO text."""
+        if self.active and next_step >= self.first + self.count:
+            self.stop()
+            with open(os.path.join(self.out_dir, self.HLO_FILE), "w") as f:
+                f.write(step_hlo())
+
+    def stop(self) -> None:
+        if self.active:
+            jax.profiler.stop_trace()
+            self.active, self.done = False, True
 
 
 # --------------------------------------------------------------------------

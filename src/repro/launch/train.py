@@ -63,7 +63,29 @@ def build_parser() -> argparse.ArgumentParser:
                     default=True,
                     help="resume from the latest checkpoint in --ckpt "
                          "(--no-resume starts fresh)")
+    ap.add_argument("--profile-dir", default=None, dest="profile_dir",
+                    help="write a jax.profiler trace of --profile-steps "
+                         "here, with the compiled step's HLO text "
+                         "(step.hlo.txt) whose op_name metadata maps each "
+                         "device op to its scope (taco/*, attn, mlp, head, "
+                         "optim)")
+    ap.add_argument("--profile-steps", default="5:4", dest="profile_steps",
+                    type=_first_count, metavar="FIRST:COUNT",
+                    help="steps to profile with --profile-dir (default 5:4)")
     return ap
+
+
+def _first_count(text: str) -> tuple[int, int]:
+    first, sep, count = text.partition(":")
+    try:
+        out = int(first), int(count)
+    except ValueError:
+        out = None
+    if not sep or out is None or out[0] < 0 or out[1] < 1:
+        raise argparse.ArgumentTypeError(
+            f"expected FIRST:COUNT with FIRST >= 0 and COUNT >= 1, "
+            f"got {text!r}")
+    return out
 
 
 def build_trainer(args, cfg=None) -> Trainer:
@@ -102,7 +124,9 @@ def build_trainer(args, cfg=None) -> Trainer:
                    total_steps=args.steps)
     tc = TrainerConfig(total_steps=args.steps,
                        ckpt_every=max(args.steps // 4, 10),
-                       log_every=10, ckpt_dir=args.ckpt or None)
+                       log_every=10, ckpt_dir=args.ckpt or None,
+                       profile_dir=args.profile_dir,
+                       profile_steps=args.profile_steps)
     return Trainer(model, mesh, ctx, oc, tc, data)
 
 

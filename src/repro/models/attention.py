@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.telemetry import SCOPE_ATTN
 from repro.models.layers import COMPUTE_DTYPE, apply_rope
 
 NEG_INF = -1e30
@@ -356,6 +357,7 @@ def sp_attention(q, k, v, ctx, *, causal, window):
 # full attention layer (train path)
 # --------------------------------------------------------------------------
 
+@jax.named_scope(SCOPE_ATTN)
 def attention_apply(x_full, p, cfg, plan, ctx, *, causal=True,
                     window=None, positions=None, kv_source=None):
     """x_full (B, S, D) -> partial output (B, S, D) (caller reduces).

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import compat
+from repro.core.telemetry import SCOPE_MLP
 COMPUTE_DTYPE = jnp.bfloat16
 
 
@@ -168,6 +169,7 @@ def mlp_specs(pb: ParamBuilder, name: str, d: int, f: int, kind: str):
     pb.add(f"{name}.w2", (f, d), fsdp_dim=1, tp_dim=0)
 
 
+@jax.named_scope(SCOPE_MLP)
 def mlp_apply(x_full, p, kind: str, ctx):
     """x_full (B, S, D) -> partial (B, S, D) — caller reduces over tp."""
     w1 = ctx.weight_gather(p["w1"], 0)

@@ -129,7 +129,7 @@ def _chunk_recurrence(r, k, v, logw, u, s0, chunk: int):
     # (n-1)/n. The recurrence is ~1-2% of layer flops (the 6*D^2 stream
     # matmuls dominate), so the roofline impact is negligible and we keep
     # the scan — unrolling 512 chunk bodies made prefill_32k lowering
-    # pathologically slow (EXPERIMENTS.md §Roofline caveat 3).
+    # pathologically slow.
     s_fin, os_ = jax.lax.scan(body, s0.astype(jnp.float32),
                               (rs, ks, vs, lw))
     o = os_.transpose(1, 0, 2, 3, 4).reshape(b, s, h, c)

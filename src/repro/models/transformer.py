@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.models import attention as attn_mod
 from repro import compat
+from repro.core.telemetry import SCOPE_HEAD
 from repro.models import moe as moe_mod
 from repro.models import rwkv as rwkv_mod
 from repro.models import ssm as ssm_mod
@@ -316,21 +317,22 @@ def forward_train(params, batch, cfg, plan, ctx):
         enc_kv = encoder_forward(params, batch["frames"], cfg, plan, ctx)
 
     # ---- embedding (vocab-parallel; TACO reduce-scatter site)
-    if cfg.frontend == "patches":
-        patches = batch["patches"].astype(COMPUTE_DTYPE)
-        idx = jax.lax.axis_index(ctx.tp_axis)
-        pat = jnp.where(idx == 0, patches, jnp.zeros_like(patches))
-        emb = embed_partial(tokens, params["embed"]["table"], ctx)
-        partial = jnp.concatenate([pat, emb], axis=1)
-        labels = jnp.concatenate(
-            [jnp.zeros(pat.shape[:2], labels.dtype), labels], axis=1)
-        mask = jnp.concatenate(
-            [jnp.zeros(pat.shape[:2], mask.dtype), mask], axis=1)
-    else:
-        partial = embed_partial(tokens, params["embed"]["table"], ctx)
-    seq = partial.shape[1]
-    x = tp_exit(partial, ctx)
-    x = add_positional(x, params, cfg, ctx, seq)
+    with jax.named_scope(SCOPE_HEAD):
+        if cfg.frontend == "patches":
+            patches = batch["patches"].astype(COMPUTE_DTYPE)
+            idx = jax.lax.axis_index(ctx.tp_axis)
+            pat = jnp.where(idx == 0, patches, jnp.zeros_like(patches))
+            emb = embed_partial(tokens, params["embed"]["table"], ctx)
+            partial = jnp.concatenate([pat, emb], axis=1)
+            labels = jnp.concatenate(
+                [jnp.zeros(pat.shape[:2], labels.dtype), labels], axis=1)
+            mask = jnp.concatenate(
+                [jnp.zeros(pat.shape[:2], mask.dtype), mask], axis=1)
+        else:
+            partial = embed_partial(tokens, params["embed"]["table"], ctx)
+        seq = partial.shape[1]
+        x = tp_exit(partial, ctx)
+        x = add_positional(x, params, cfg, ctx, seq)
 
     positions = jnp.arange(seq)
     if ctx.sp_active:
@@ -339,8 +341,9 @@ def forward_train(params, batch, cfg, plan, ctx):
                           cfg, plan, ctx,
                           positions=positions, enc_kv=enc_kv,
                           causal=True)
-    x = apply_norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-    x_full = tp_enter(x, ctx)                             # TACO gather site
-    loss_sum, count = vocab_parallel_xent(
-        x_full, head_table(params, cfg), labels, mask, ctx, plan)
+    with jax.named_scope(SCOPE_HEAD):
+        x = apply_norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
+        x_full = tp_enter(x, ctx)                         # TACO gather site
+        loss_sum, count = vocab_parallel_xent(
+            x_full, head_table(params, cfg), labels, mask, ctx, plan)
     return loss_sum, count, aux

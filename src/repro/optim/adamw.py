@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.models.layers import ParamSpec
 from repro import compat
+from repro.core.telemetry import SCOPE_OPTIM
 
 IS_SPEC = lambda x: isinstance(x, ParamSpec)  # noqa: E731
 
@@ -67,6 +68,7 @@ def opt_state_pspecs(param_pspecs):
             "step": P()}
 
 
+@jax.named_scope(SCOPE_OPTIM)
 def finalize_grads(grads, model):
     """psum grads of replicated-but-divergently-used params (norm scales,
     replicated-kv weights, router) over the axes they're replicated on."""
@@ -105,6 +107,7 @@ def global_grad_norm(grads, model):
     return jnp.sqrt(sq)
 
 
+@jax.named_scope(SCOPE_OPTIM)
 def adamw_update(grads, opt_state, oc: OptConfig, model):
     """grads: finalized local-shard grads. Returns (new_bf16_params,
     new_opt_state, metrics)."""

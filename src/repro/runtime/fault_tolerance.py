@@ -31,16 +31,22 @@ class StepWatchdog:
     _times: list = dataclasses.field(default_factory=list)
     stragglers: int = 0
 
-    def observe(self, seconds: float) -> bool:
-        """Returns True if this step is a straggler."""
+    def observe(self, seconds: float,
+                phases: dict[str, float] | None = None) -> bool:
+        """Returns True if this step is a straggler.  ``phases`` is the
+        step's host split in milliseconds (``telemetry.StepPhases``),
+        printed with the warning."""
         is_straggler = False
         if len(self._times) >= 5:
             med = statistics.median(self._times[-self.window:])
             if seconds > self.straggler_factor * med:
                 self.stragglers += 1
                 is_straggler = True
-                log.warning("straggler step: %.3fs vs median %.3fs",
-                            seconds, med)
+                split = ", ".join(
+                    f"{k} {v:.1f}" + (" ms" if i == 0 else "")
+                    for i, (k, v) in enumerate((phases or {}).items()))
+                log.warning("straggler step: %.3fs vs median %.3fs%s",
+                            seconds, med, f" ({split})" if split else "")
         self._times.append(seconds)
         if len(self._times) > 2 * self.window:
             del self._times[:self.window]

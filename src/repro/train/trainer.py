@@ -20,13 +20,19 @@ paths an :class:`repro.core.policy.ErrorEscalationController`
 (error-driven fallback-codec swaps) — both ride the same cached-step-fn
 mechanism.  The normalized spec is persisted in every checkpoint
 manifest and validated on restore; per-path wire-byte telemetry is
-merged into the metrics dict every step.
+logged on log steps.
+
+Each iteration is a profiler step span (``train``, with its step number)
+holding one span per host phase (``train/data``, ``train/place``,
+``train/dispatch``, ``train/sync``, ``train/log``, ``train/ckpt``; names
+and meanings in :mod:`repro.core.telemetry`); the straggler watchdog
+prints the step's split.  ``TrainerConfig.profile_dir`` captures a trace
+of ``profile_steps`` with the compiled step's HLO text beside it.
 """
 from __future__ import annotations
 
 import dataclasses
 import logging
-import time
 
 import jax
 import numpy as np
@@ -52,6 +58,8 @@ class TrainerConfig:
     ckpt_dir: str | None = "/tmp/repro_ckpt"   # None: no checkpoints
     keep_last: int = 3
     seed: int = 0
+    profile_dir: str | None = None   # trace + step HLO of profile_steps
+    profile_steps: tuple[int, int] = (5, 4)   # (first step, count)
 
 
 class Trainer:
@@ -148,57 +156,85 @@ class Trainer:
         step = start
         stepped = False   # has any step completed in this process?
         bspecs = self.model.batch_pspecs()
-        while step < self.tc.total_steps:
-            try:
-                if self.injector:
-                    self.injector.maybe_fail(step)
-                batch = self.data.place(self.data.batch(step), self.mesh,
-                                        bspecs)
-                t0 = time.time()
-                # the engine resolves the step's plan, dispatches the
-                # cached compiled step, ticks every controller, and
-                # replays an invalidated step (slot-overflow resync)
-                # until it lands clean — donation is off in that mode,
-                # so the inputs stay alive across a replay
-                (new_params, new_opt, metrics), plan = self.policy.run(
-                    step, lambda fn: fn(params, opt_state, batch))
-                params, opt_state = new_params, new_opt
-                loss = float(metrics["loss"])
-                dt = time.time() - t0
-                self.watchdog.observe(dt)
-                self.losses.append(loss)
-                # per-path wire-byte telemetry for the plan that actually
-                # ran this step (static — no extra device work); shared
-                # key set with the serving engine's run summary
-                metrics.update(telemetry.comm_metrics(
-                    plan, spec=self.comm_spec,
-                    warmup_active=self.policy.warmup_active(step)))
-                metrics.update(self.policy.metrics())
-                if step % self.tc.log_every == 0:
-                    log.info("step %d loss %.4f gnorm %.3f lr %.2e (%.2fs) "
-                             "tp_wire %.3fB/elem",
-                             step, loss, float(metrics["grad_norm"]),
-                             float(metrics["lr"]), dt,
-                             metrics["comm/tp_fwd_bytes_per_elem"])
-                step += 1
-                stepped = True
-                if self.tc.ckpt_dir is not None and (
-                        step % self.tc.ckpt_every == 0
-                        or step == self.tc.total_steps):
-                    ckpt.save(self.tc.ckpt_dir, step,
-                              {"params": params, "opt": opt_state},
-                              keep_last=self.tc.keep_last,
-                              comm_spec=self.comm_spec)
-            except Exception as exc:  # noqa: BLE001 — restart boundary
-                # before the first completed step a failure is a build
-                # fault (compile error, device out of memory): rebuilding
-                # cannot fix it, so it surfaces at once
-                if not stepped or not retry.should_retry(exc):
-                    raise
-                params, opt_state, start = self.init_state()
-                restored = self.try_restore(params, opt_state)
-                if restored is not None:
-                    params, opt_state, step = restored[0], restored[1], restored[2]
-                else:
-                    step = 0
+        phases = telemetry.StepPhases()
+        phase = phases.phase
+        profile = None if self.tc.profile_dir is None else \
+            telemetry.StepProfile(self.tc.profile_dir, *self.tc.profile_steps)
+        try:
+            while step < self.tc.total_steps:
+                try:
+                    if self.injector:
+                        self.injector.maybe_fail(step)
+                    if profile:
+                        profile.before(step)
+                    with phases.step(step):
+                        with phase(telemetry.SPAN_DATA):
+                            rows = self.data.batch(step)
+                        with phase(telemetry.SPAN_PLACE):
+                            batch = self.data.place(rows, self.mesh, bspecs)
+                        # the engine resolves the step's plan, dispatches
+                        # the cached compiled step, ticks every controller,
+                        # and replays an invalidated step (slot-overflow
+                        # resync) until it lands clean — donation is off in
+                        # that mode, so the inputs stay alive across a replay
+                        with phase(telemetry.SPAN_DISPATCH):
+                            (params, opt_state, metrics), plan = \
+                                self.policy.run(step, lambda fn: fn(
+                                    params, opt_state, batch))
+                        with phase(telemetry.SPAN_SYNC):
+                            loss = float(metrics["loss"])
+                        self.losses.append(loss)
+                        with phase(telemetry.SPAN_LOG):
+                            if step % self.tc.log_every == 0 \
+                                    and log.isEnabledFor(logging.INFO):
+                                self._log_step(step, loss, metrics, plan,
+                                               phases.ms)
+                            split = phases.split()
+                            self.watchdog.observe(
+                                (split["dispatch"] + split["sync"]) * 1e-3,
+                                split)
+                        step += 1
+                        stepped = True
+                        if self.tc.ckpt_dir is not None and (
+                                step % self.tc.ckpt_every == 0
+                                or step == self.tc.total_steps):
+                            with phase(telemetry.SPAN_CKPT):
+                                ckpt.save(self.tc.ckpt_dir, step,
+                                          {"params": params,
+                                           "opt": opt_state},
+                                          keep_last=self.tc.keep_last,
+                                          comm_spec=self.comm_spec)
+                    if profile:
+                        profile.after(step, lambda: self.step_fn_for(
+                            step - 1)[0].lower(params, opt_state, batch)
+                            .compile().as_text())
+                except Exception as exc:  # noqa: BLE001 — restart boundary
+                    # before the first completed step a failure is a build
+                    # fault (compile error, device out of memory):
+                    # rebuilding cannot fix it, so it surfaces at once
+                    if not stepped or not retry.should_retry(exc):
+                        raise
+                    params, opt_state, start = self.init_state()
+                    restored = self.try_restore(params, opt_state)
+                    if restored is not None:
+                        params, opt_state, step = restored
+                    else:
+                        step = 0
+        finally:
+            if profile:
+                profile.stop()
         return params, opt_state, self.losses
+
+    def _log_step(self, step, loss, metrics, plan, ms):
+        """The log step's line; its ``comm/*`` and controller telemetry
+        (static per-path wire accounting of the plan that ran) at DEBUG."""
+        tele = telemetry.comm_metrics(
+            plan, spec=self.comm_spec,
+            warmup_active=self.policy.warmup_active(step))
+        tele.update(self.policy.metrics())
+        log.info("step %d loss %.4f gnorm %.3f lr %.2e (%.2fs) "
+                 "tp_wire %.3fB/elem", step, loss,
+                 float(metrics["grad_norm"]), float(metrics["lr"]),
+                 (ms["dispatch"] + ms["sync"]) * 1e-3,
+                 tele["comm/tp_fwd_bytes_per_elem"])
+        log.debug("step %d telemetry %s", step, tele)

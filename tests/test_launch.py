@@ -2,6 +2,7 @@
 from pathlib import Path
 
 import jax
+import pytest
 
 from repro.launch import compile_cache
 from repro.launch.train import build_parser
@@ -45,3 +46,19 @@ def test_resume_flag_can_be_turned_off():
     assert ap.parse_args([]).resume is True
     assert ap.parse_args(["--no-resume"]).resume is False
     assert ap.parse_args(["--ckpt", ""]).ckpt == ""
+
+
+@pytest.mark.parametrize("argv, want", [
+    ([], (None, (5, 4))),
+    (["--profile-dir", "/p", "--profile-steps", "0:2"], ("/p", (0, 2))),
+])
+def test_profile_flags(argv, want):
+    args = build_parser().parse_args(argv)
+    assert (args.profile_dir, args.profile_steps) == want
+
+
+@pytest.mark.parametrize("bad", ["5", "5:0", "-1:4", "a:b", "5:4:3"])
+def test_profile_steps_rejects_malformed_ranges(bad, capsys):
+    with pytest.raises(SystemExit):
+        build_parser().parse_args(["--profile-steps", bad])
+    assert "FIRST:COUNT" in capsys.readouterr().err

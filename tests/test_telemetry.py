@@ -174,3 +174,39 @@ def test_clear_probe_cache():
     assert not telemetry._PROBE_RATIO_CACHE
     # recompute lands on the same value (the floor is deterministic)
     assert telemetry.achieved_probe_ratio(codec) == ratio
+
+
+# --------------------------------------------------------------------------
+# the training loop's phase split
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("slow_phase", ["sync", "data"])
+def test_straggler_warning_prints_the_phase_split(caplog, slow_phase):
+    """The watchdog's straggler warning carries the step's host split,
+    in loop order, the open phase timed up to the warning."""
+    import logging
+    import time
+
+    from repro.runtime.fault_tolerance import StepWatchdog
+
+    wd = StepWatchdog()
+    for _ in range(5):
+        assert not wd.observe(0.01, {"sync": 10.0})
+    ph = telemetry.StepPhases()
+    with ph.step(7):
+        for name in ("train/data", "train/place", "train/dispatch",
+                     "train/sync"):
+            with ph.phase(name):
+                if name.endswith(slow_phase):
+                    time.sleep(0.05)
+        with ph.phase(telemetry.SPAN_LOG):
+            split = ph.split()
+            with caplog.at_level(logging.WARNING, logger="repro.ft"):
+                assert wd.observe(0.05, split)
+    assert list(split) == ["data", "place", "dispatch", "sync", "log"]
+    assert split[slow_phase] >= 50.0
+    assert ph.ms["log"] >= split["log"]
+    msg, = [r.getMessage() for r in caplog.records]
+    assert msg.startswith("straggler step: 0.050s vs median 0.010s (data ")
+    assert f"{slow_phase} {split[slow_phase]:.1f}" in msg
+    assert msg.endswith(f"log {split['log']:.1f})")

@@ -137,3 +137,66 @@ def test_no_checkpoint_dir_writes_nothing(tmp_path):
     tr = Trainer(model, mesh1(), ctx, oc, tc, data)
     _, _, losses = tr.run(resume=True)
     assert len(losses) == 2 and list(tmp_path.iterdir()) == []
+
+
+# --------------------------------------------------------------------------
+# the loop's spans and the step's scopes (--profile-dir)
+# --------------------------------------------------------------------------
+
+PHASES = ["train/data", "train/place", "train/dispatch", "train/sync",
+          "train/log"]
+
+
+def _loop_spans(profile_dir):
+    """``{step_num: (start, end, [(start, phase), ...])}`` from the
+    trace's host plane."""
+    import glob
+    from jax.profiler import ProfileData
+    path, = glob.glob(f"{profile_dir}/plugins/profile/*/*.xplane.pb")
+    steps, phases = {}, []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                end = ev.start_ns + ev.duration_ns
+                if ev.name == "train":
+                    steps[dict(ev.stats)["step_num"]] = (ev.start_ns, end, [])
+                elif ev.name.startswith("train/"):
+                    phases.append((ev.start_ns, end, ev.name))
+    for s, e, name in sorted(phases):
+        for lo, hi, inside in steps.values():
+            if lo <= s and e <= hi:
+                inside.append(name)
+    return steps
+
+
+@pytest.mark.parametrize("spec", ["taco", "baseline"])
+def test_profiled_steps_carry_loop_spans_and_step_scopes(tmp_path, spec):
+    """``profile_dir`` traces the chosen steps: each is one ``train``
+    span with its step number holding the host phases in loop order; the
+    compiled step's HLO text beside the trace carries every named scope
+    in its op_name metadata (no ``taco/`` scope without compression)."""
+    import re
+    from repro.core import telemetry
+    model, ctx, oc, tc, data = small_setup(tmp_path, spec, total_steps=4)
+    tc.profile_dir = str(tmp_path / "profile")
+    tc.profile_steps = (1, 3)
+    tr = Trainer(model, mesh1(), ctx, oc, tc, data)
+    tr.run(resume=False)
+    steps = _loop_spans(tc.profile_dir)
+    assert sorted(steps) == [1, 2, 3]
+    for n, (_, _, inside) in steps.items():
+        # step 3 is the last: its checkpoint is saved inside the span
+        assert inside == PHASES + (["train/ckpt"] if n == 3 else []), n
+
+    with open(tmp_path / "profile" / telemetry.StepProfile.HLO_FILE) as f:
+        names = set(re.findall(r'op_name="([^"]*)"', f.read()))
+
+    def has(scope):
+        pat = re.compile(r"(^|[/(])" + re.escape(scope) + r"([/)]|$)")
+        return any(pat.search(n) for n in names)
+
+    for scope in telemetry.STEP_SCOPES:
+        assert has(scope) == (spec == "taco" or not scope.startswith(
+            "taco/")), scope
