@@ -14,8 +14,8 @@ on):
 The training loop's host phases are profiler spans (:class:`StepPhases`,
 names below), and :class:`StepProfile` captures a profiler trace of a
 few steps plus the compiled step's HLO text, whose ``op_name`` metadata
-carries the step's named scopes (``taco/*``, ``attn``, ``mlp``, ``head``,
-``optim``) for the device ops of the trace.
+carries the step's named scopes (``taco/*``, ``attn``, ``attn/core``,
+``mlp``, ``head``, ``optim``) for the device ops of the trace.
 
 Everything here is host-side Python on static plan data — the only
 device work is the one cached probe encode behind
@@ -69,6 +69,9 @@ SCOPE_DECODE = "taco/decode"
 SCOPE_WIRE = "taco/wire"
 #: Attention: projections, core and output projection.
 SCOPE_ATTN = "attn"
+#: The attention core inside ``attn``: the chunked softmax, forward and
+#: recompute backward.
+SCOPE_ATTN_CORE = SCOPE_ATTN + "/core"
 #: The feed-forward block.
 SCOPE_MLP = "mlp"
 #: Embedding, final norm, output head and loss.
@@ -76,7 +79,8 @@ SCOPE_HEAD = "head"
 #: Gradient finalisation and the AdamW update.
 SCOPE_OPTIM = "optim"
 STEP_SCOPES = (SCOPE_ENCODE, SCOPE_MOVE, SCOPE_DECODE, SCOPE_WIRE,
-               SCOPE_ATTN, SCOPE_MLP, SCOPE_HEAD, SCOPE_OPTIM)
+               SCOPE_ATTN, SCOPE_ATTN_CORE, SCOPE_MLP, SCOPE_HEAD,
+               SCOPE_OPTIM)
 
 
 class StepPhases:

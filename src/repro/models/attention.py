@@ -5,24 +5,33 @@ n_kv >= tp the kv heads are group-padded and sharded alongside q; else the
 (few) kv heads are stored replicated across the model axis and each device
 statically selects the kv head(s) its local q heads map to.
 
-The attention core is a flash-style two-level chunked scan in pure JAX
-(f32 softmax accumulators). Sliding-window attention slices a static
-(W + Cq)-wide kv window per q chunk, giving true O(S*W) cost — this is
-what qualifies SWA archs for long_500k.
+The attention core is a two-level chunked scan in pure JAX: an online
+softmax over kv chunks with f32 accumulators, inside a map over q chunks.
+Its backward is flash-style (a custom VJP): it recomputes each chunk
+pair's probabilities from the saved per-row log-sum-exp, so no
+O(Sq*Sk) buffer is stored for it. Sliding-window attention slices a
+static (W + Cq)-wide kv window per q chunk, giving true O(S*W) cost —
+this is what qualifies SWA archs for long_500k.
 
 Dead (padding) q heads are masked out of the output so their parameter
 gradients are exactly zero (keeps padded model == unpadded reference).
 """
 from __future__ import annotations
 
+import collections
+import functools
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.telemetry import SCOPE_ATTN
+from repro.core.telemetry import SCOPE_ATTN, SCOPE_ATTN_CORE
 from repro.models.layers import COMPUTE_DTYPE, apply_rope
 
 NEG_INF = -1e30
+#: Opened inside ``attn`` (attention_apply), so op_names read SCOPE_ATTN_CORE.
+_SCOPE_CORE = SCOPE_ATTN_CORE.rpartition("/")[2]
 
 
 # --------------------------------------------------------------------------
@@ -111,21 +120,79 @@ def qkv_project(x_full, p, cfg, plan, ctx, positions):
 
 
 # --------------------------------------------------------------------------
-# flash-style chunked attention core
+# chunked attention core: online-softmax forward, recompute backward
 # --------------------------------------------------------------------------
 
+#: Trace-time count of the backward each ``attention_core`` call took:
+#: ``recompute`` (the custom VJP) or ``autodiff`` (analysis mode only).
+BACKWARD_TRACES: collections.Counter = collections.Counter()
+
+
+class _Chunks(NamedTuple):
+    """Static chunking of one core call."""
+    causal: bool
+    window: int | None
+    q_chunk: int
+    kv_chunk: int
+    nq: int
+    unroll: bool        # python-unrolled q chunks (analysis mode)
+
+
+def _chunked(x, n):
+    """(B,H,S,...) -> (n,B,H,S/n,...)."""
+    b, h, s = x.shape[:3]
+    return jnp.moveaxis(x.reshape(b, h, n, s // n, *x.shape[3:]), 2, 0)
+
+
+def _unchunked(xs):
+    """Inverse of :func:`_chunked`."""
+    n, b, h, c = xs.shape[:4]
+    return jnp.moveaxis(xs, 0, 2).reshape(b, h, n * c, *xs.shape[4:])
+
+
+def _kv_span(c, qi, sk, q_offset, kv_len):
+    """Query chunk ``qi``'s kv range ``[lo, lo + width)`` and its additive
+    mask ``mask_fn(kv_start, ck) -> (Cq, ck)`` (kv_start relative to lo).
+    Sliding windows take a static (W + Cq)-wide range."""
+    q_pos = q_offset + qi * c.q_chunk + jnp.arange(c.q_chunk)
+    if c.window is None:
+        lo, width = 0, sk
+    else:
+        w = min(c.window, sk)
+        width = min(w + c.q_chunk, sk)
+        lo = jnp.clip(q_pos[0] - w + 1, 0, sk - width)
+
+    def mask_fn(kv_start, ck):
+        kpos = kv_start + jnp.arange(ck)
+        if c.window is not None:
+            kpos = lo + kpos
+        m = jnp.zeros((c.q_chunk, ck), jnp.float32)
+        if c.causal or c.window is not None:
+            m = jnp.where(kpos[None, :] > q_pos[:, None], NEG_INF, m)
+        if c.window is not None:
+            m = jnp.where(kpos[None, :] <= q_pos[:, None] - w, NEG_INF, m)
+        if kv_len is not None:
+            m = jnp.where(kpos[None, :] >= kv_len, NEG_INF, m)
+        return m
+
+    return lo, width, mask_fn
+
+
+def _kv_slice(x, lo, width):
+    if width == x.shape[2]:
+        return x
+    return jax.lax.dynamic_slice_in_dim(x, lo, width, axis=2)
+
+
 def _softmax_scan(q, k, v, mask_fn, kv_chunk: int):
-    """q (B,H,Cq,hd) vs k,v (B,H,Sk,hd) -> (B,H,Cq,hd). Online softmax over
-    kv chunks; mask_fn(kv_start, ck) -> (Cq, ck) additive mask."""
+    """q (B,H,Cq,hd) vs k,v (B,H,Sk,hd) -> (B,H,Cq,hd) and the rows'
+    log-sum-exp (B,H,Cq). Online softmax over kv chunks."""
     b, h, cq, hd = q.shape
     sk = k.shape[2]
     kv_chunk = min(kv_chunk, sk)
     n = sk // kv_chunk
     scale = 1.0 / np.sqrt(hd)
     qf = q.astype(jnp.float32) * scale
-
-    ks = k.reshape(b, h, n, kv_chunk, hd).transpose(2, 0, 1, 3, 4)
-    vs = v.reshape(b, h, n, kv_chunk, hd).transpose(2, 0, 1, 3, 4)
 
     def body(carry, inp):
         acc, m, l = carry
@@ -143,8 +210,104 @@ def _softmax_scan(q, k, v, mask_fn, kv_chunk: int):
     init = (jnp.zeros((b, h, cq, hd), jnp.float32),
             jnp.full((b, h, cq), NEG_INF, jnp.float32),
             jnp.zeros((b, h, cq), jnp.float32))
-    (acc, m, l), _ = jax.lax.scan(body, init, (ks, vs, jnp.arange(n)))
-    return acc / jnp.maximum(l, 1e-30)[..., None]
+    (acc, m, l), _ = jax.lax.scan(
+        body, init, (_chunked(k, n), _chunked(v, n), jnp.arange(n)))
+    return acc / jnp.maximum(l, 1e-30)[..., None], m + jnp.log(l)
+
+
+def _softmax_scan_bwd(q, k, v, do, lse, delta, mask_fn, kv_chunk: int):
+    """Backward of :func:`_softmax_scan` for one q chunk: each kv chunk's
+    probabilities are recomputed from the rows' log-sum-exp, never
+    stored. Returns f32 dq (B,H,Cq,hd) and dk, dv (B,H,Sk,hd)."""
+    b, h, cq, hd = q.shape
+    sk = k.shape[2]
+    kv_chunk = min(kv_chunk, sk)
+    n = sk // kv_chunk
+    scale = 1.0 / np.sqrt(hd)
+    qf = q.astype(jnp.float32) * scale
+
+    def body(dq, inp):
+        kc, vc, j = inp
+        kf, vf = kc.astype(jnp.float32), vc.astype(jnp.float32)
+        s_ = jnp.einsum("bhqd,bhkd->bhqk", qf, kf)
+        s_ = s_ + mask_fn(j * kv_chunk, kv_chunk)[None, None]
+        p_ = jnp.exp(s_ - lse[..., None])
+        dp = jnp.einsum("bhqd,bhkd->bhqk", do, vf)
+        ds = p_ * (dp - delta[..., None])
+        dq = dq + jnp.einsum("bhqk,bhkd->bhqd", ds, kf)
+        return dq, (jnp.einsum("bhqk,bhqd->bhkd", ds, qf),
+                    jnp.einsum("bhqk,bhqd->bhkd", p_, do))
+
+    dq, (dks, dvs) = jax.lax.scan(
+        body, jnp.zeros((b, h, cq, hd), jnp.float32),
+        (_chunked(k, n), _chunked(v, n), jnp.arange(n)))
+    return dq * scale, _unchunked(dks), _unchunked(dvs)
+
+
+def _forward(c, qt, kt, vt, q_offset, kv_len):
+    """Head-major q (B,H,Sq,hd), k/v (B,H,Sk,hd) -> f32 output
+    (B,H,Sq,hd) and the rows' log-sum-exp (B,H,Sq)."""
+    sk = kt.shape[2]
+
+    def one_q_chunk(qi):
+        qc = jax.lax.dynamic_slice_in_dim(qt, qi * c.q_chunk, c.q_chunk,
+                                          axis=2)
+        lo, width, mask_fn = _kv_span(c, qi, sk, q_offset, kv_len)
+        return _softmax_scan(qc, _kv_slice(kt, lo, width),
+                             _kv_slice(vt, lo, width), mask_fn, c.kv_chunk)
+
+    if c.nq == 1:
+        return one_q_chunk(0)
+    if c.unroll:
+        outs = [one_q_chunk(i) for i in range(c.nq)]
+        outs = tuple(jnp.stack(x) for x in zip(*outs))
+    else:
+        outs = jax.lax.map(one_q_chunk, jnp.arange(c.nq))  # (nq,B,H,Cq,...)
+    return tuple(_unchunked(x) for x in outs)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _attend(c, qt, kt, vt, q_offset, kv_len):
+    return _forward(c, qt, kt, vt, q_offset, kv_len)[0]
+
+
+def _attend_fwd(c, qt, kt, vt, q_offset, kv_len):
+    out, lse = _forward(c, qt, kt, vt, q_offset, kv_len)
+    return out, (qt, kt, vt, q_offset, kv_len, out, lse)
+
+
+def _attend_bwd(c, res, dout):
+    """Flash-style backward: per q chunk, recompute every kv chunk's
+    probabilities from the saved log-sum-exp; dK/dV accumulate in f32
+    across q chunks (into the chunk's window under SWA). Residuals are
+    O(S*hd); nothing O(Sq*Sk) outlives one chunk pair."""
+    qt, kt, vt, q_offset, kv_len, out, lse = res
+    sk = kt.shape[2]
+    delta = jnp.sum(dout * out, axis=-1)                    # (B,H,Sq)
+
+    def one_q_chunk(carry, qi):
+        start = qi * c.q_chunk
+        qc, doc, lse_c, delta_c = (
+            jax.lax.dynamic_slice_in_dim(x, start, c.q_chunk, axis=2)
+            for x in (qt, dout, lse, delta))
+        lo, width, mask_fn = _kv_span(c, qi, sk, q_offset, kv_len)
+        dq_c, *dkv_c = _softmax_scan_bwd(
+            qc, _kv_slice(kt, lo, width), _kv_slice(vt, lo, width), doc,
+            lse_c, delta_c, mask_fn, c.kv_chunk)
+        carry = tuple(
+            acc + x if width == sk else jax.lax.dynamic_update_slice_in_dim(
+                acc, _kv_slice(acc, lo, width) + x, lo, axis=2)
+            for acc, x in zip(carry, dkv_c))
+        return carry, dq_c
+
+    zeros = jnp.zeros(kt.shape, jnp.float32)
+    (dk, dv), dqs = jax.lax.scan(one_q_chunk, (zeros, zeros),
+                                 jnp.arange(c.nq))
+    return (_unchunked(dqs).astype(qt.dtype), dk.astype(kt.dtype),
+            dv.astype(vt.dtype), None, None)
+
+
+_attend.defvjp(_attend_fwd, _attend_bwd)
 
 
 def attention_core(q, k, v, *, causal: bool, window: int | None,
@@ -154,67 +317,29 @@ def attention_core(q, k, v, *, causal: bool, window: int | None,
 
     q_offset: global position of q[0] (decode / chunked prefill).
     kv_len: actual valid kv length (<= Sk) for cache attention.
+    Both are serving-only and take no gradient.
     """
     from repro.models import analysis_mode
-    b, sq, h, hd = q.shape
-    sk = k.shape[1]
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-
-    if analysis_mode.on():
+    sq = q.shape[1]
+    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    unroll = analysis_mode.on()
+    if unroll:
         # single-trip (full attn) / python-unrolled (SWA) so cost analysis
         # sees every chunk — see models/analysis_mode.py
         q_chunk = sq if window is None else min(2048, sq)
-        kv_chunk = sk
+        kv_chunk = k.shape[1]
     q_chunk = min(q_chunk, sq)
-    nq = sq // q_chunk if sq % q_chunk == 0 else 1
-    if sq % q_chunk != 0:
+    if sq % q_chunk:
         q_chunk = sq
-
-    def one_q_chunk(qi):
-        qc = jax.lax.dynamic_slice_in_dim(qt, qi * q_chunk, q_chunk, axis=2)
-        q_pos = q_offset + qi * q_chunk + jnp.arange(q_chunk)
-        if window is not None:
-            # static-width kv window: [lo, lo + W + Cq)
-            w = min(window, sk)
-            width = min(w + q_chunk, sk)
-            lo = jnp.clip(q_pos[0] - w + 1, 0, sk - width)
-            kc = jax.lax.dynamic_slice_in_dim(kt, lo, width, axis=2)
-            vc = jax.lax.dynamic_slice_in_dim(vt, lo, width, axis=2)
-
-            def mask_fn(kv_start, ck, lo=lo):
-                kpos = lo + kv_start + jnp.arange(ck)
-                m = jnp.zeros((q_chunk, ck), jnp.float32)
-                m = jnp.where(kpos[None, :] > q_pos[:, None], NEG_INF, m)
-                m = jnp.where(kpos[None, :] <= q_pos[:, None] - w, NEG_INF, m)
-                if kv_len is not None:
-                    m = jnp.where(kpos[None, :] >= kv_len, NEG_INF, m)
-                return m
-
-            return _softmax_scan(qc, kc, vc, mask_fn, kv_chunk)
-
-        def mask_fn(kv_start, ck):
-            kpos = kv_start + jnp.arange(ck)
-            m = jnp.zeros((q_chunk, ck), jnp.float32)
-            if causal:
-                m = jnp.where(kpos[None, :] > q_pos[:, None], NEG_INF, m)
-            if kv_len is not None:
-                m = jnp.where(kpos[None, :] >= kv_len, NEG_INF, m)
-            return m
-
-        return _softmax_scan(qc, kt, vt, mask_fn, kv_chunk)
-
-    if nq == 1:
-        out = one_q_chunk(0)
-    elif analysis_mode.on():
-        outs = jnp.stack([one_q_chunk(i) for i in range(nq)])
-        out = outs.transpose(1, 2, 0, 3, 4).reshape(b, h, sq, hd)
-        return out.transpose(0, 2, 1, 3).astype(COMPUTE_DTYPE)
-    else:
-        outs = jax.lax.map(one_q_chunk, jnp.arange(nq))     # (nq,B,H,Cq,hd)
-        out = outs.transpose(1, 2, 0, 3, 4).reshape(b, h, sq, hd)
-        return out.transpose(0, 2, 1, 3).astype(COMPUTE_DTYPE)
+    c = _Chunks(causal, window, q_chunk, kv_chunk, sq // q_chunk, unroll)
+    with jax.named_scope(_SCOPE_CORE):
+        if unroll:
+            # autodiff through the unrolled chunks: cost analysis only
+            BACKWARD_TRACES["autodiff"] += 1
+            out = _forward(c, qt, kt, vt, q_offset, kv_len)[0]
+        else:
+            BACKWARD_TRACES["recompute"] += 1
+            out = _attend(c, qt, kt, vt, q_offset, kv_len)
     return out.transpose(0, 2, 1, 3).astype(COMPUTE_DTYPE)
 
 
