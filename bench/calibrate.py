@@ -12,7 +12,8 @@ below the configuration's stated compute precision: float8 operands
 under bfloat16, with the configuration's wire as it is) takes the
 program's place, and on the first
 ``--fault-seeds`` seeds each fault the cell can have does: half of every
-row left out of the mean, one leaf moved double, and on a cell of
+row left out of the mean, one leaf (the reference's ``FAULT_LEAF``)
+moved double, and on a cell of
 several chips the exchange between them left out.  A state left
 unchanged reads 1 by construction and is not run.  One JSON line per
 seed.  The benchmark's own runs never run this.
@@ -32,7 +33,6 @@ import compare
 import harness
 import refmodel
 
-DOUBLED_LEAF = "layers/mlp/w1"
 CONTROL = {"bfloat16": refmodel.Variant(fp8=True)}   # one step below
 
 
@@ -57,7 +57,8 @@ def main(argv=None, *, require_chip: bool = True, benchmark=None):
     control = CONTROL[cell.config["precision"]["compute"]]
     variants = {"control": (control, False),
                 "half_batch": (refmodel.SOUND, True),
-                "leaf_double": (refmodel.Variant(double=DOUBLED_LEAF), False)}
+                "leaf_double": (refmodel.Variant(
+                    double=cell.reference.FAULT_LEAF), False)}
     if cell.tp > 1:
         variants["no_exchange"] = (refmodel.Variant(no_exchange=True), False)
     for i, seed in enumerate(seeds):

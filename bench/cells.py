@@ -4,7 +4,10 @@
 rest of a cell lives in files of its own, found by name:
 
 * ``bench/configs/<file>``: the model configuration as it is run (its
-  ``arch`` block) with its published source;
+  ``arch`` block) with its published source, and the name of its plain
+  reference, ``"reference": "<module>"``, found as ``bench/<module>.py``
+  (``refmodel`` where the key is absent; ``refmodel.py`` says what a
+  reference provides);
 * ``bench/traffic/<traffic>.json``: the parameters of the packed-document
   generator and the rows per step (sequence length and micro-batch);
 * ``bench/cells/<workload>.json``: how the trainer runs the cell (the
@@ -18,7 +21,10 @@ rest of a cell lives in files of its own, found by name:
 from __future__ import annotations
 
 import dataclasses
+import importlib
+import importlib.util
 import json
+import sys
 from pathlib import Path
 
 BENCH = Path(__file__).resolve().parent
@@ -34,6 +40,7 @@ class Cell:
     run: dict
     end_to_end: list
     per_layer: list
+    reference: object    # the configuration's reference module
 
     @property
     def arch(self) -> dict:
@@ -58,6 +65,23 @@ def _read(path: Path) -> dict:
         return json.load(f)
 
 
+def reference(name: str, base: Path):
+    """The reference module ``name``: ``<base>/<name>.py``, else a module
+    of ``bench/`` (on the import path), imported once per process."""
+    if not name.isidentifier():
+        raise SystemExit(f"reference {name!r} is not a module name")
+    if name in sys.modules:
+        return sys.modules[name]
+    path = base / f"{name}.py"
+    if not path.is_file():
+        return importlib.import_module(name)
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load(workload: str, benchmark: Path | None = None) -> Cell:
     """The cell of ``workload``.  With ``benchmark`` (a test's own
     ``BENCHMARK.json``), files are found beside it, as ``bench/`` and the
@@ -78,11 +102,13 @@ def load(workload: str, benchmark: Path | None = None) -> Cell:
     per_layer = [m for m in bench["per_layer"]
                  if (workload in m["workloads"] if "workloads" in m
                      else m["moves"] in moves)]
-    return Cell(name=workload, chips=int(w["chips"]),
-                config=_read(root / conf["file"]),
+    config = _read(root / conf["file"])
+    return Cell(name=workload, chips=int(w["chips"]), config=config,
                 traffic=_read(base / "traffic" / f"{w['traffic']}.json"),
                 run=_read(base / "cells" / f"{workload}.json"),
-                end_to_end=e2e, per_layer=per_layer)
+                end_to_end=e2e, per_layer=per_layer,
+                reference=reference(config.get("reference", "refmodel"),
+                                    base))
 
 
 def peaks(device_kind: str) -> dict:

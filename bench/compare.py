@@ -4,7 +4,8 @@ Both sides give, for the first three steps of the same weights and rows:
 the loss of each step, the per-leaf norms of the first gradient as the
 optimizer used it (after clipping), and the per-leaf norms of the change
 of the float32 parameters after the three updates.  A stacked layer
-leaf counts as one leaf per layer.  Three numbers come out:
+leaf (``layers/...``, or ``layers<i>/...`` for a later segment) counts
+as one leaf per layer.  Three numbers come out:
 
 * ``loss_gap``: the largest relative gap of a step's loss;
 * ``grad_gap``: the worst leaf's gap between the two gradient norms,
@@ -43,6 +44,8 @@ import math
 
 import numpy as np
 
+from weights import stacked
+
 SAMPLE = 2048  # gradient elements sampled per leaf (per layer)
 
 STILL = 1e-3   # a leaf under this share of the median gradient norm
@@ -54,11 +57,11 @@ def flat(norms: dict) -> dict[str, float]:
     out = {}
     for k, v in norms.items():
         v = np.asarray(v, np.float64)
-        if v.ndim == 0:
-            out[k] = float(v)
-        else:
+        if stacked(k):
             for i, x in enumerate(v):
                 out[f"{k}.{i}"] = float(x)
+        else:
+            out[k] = float(v)
     return out
 
 
@@ -82,7 +85,7 @@ def sample_index(shapes: dict, seed: int) -> dict:
     out = {}
     for k in sorted(shapes):
         s = shapes[k]
-        if k.startswith("layers/"):
+        if stacked(k):
             out[k] = rng.integers(0, int(np.prod(s[1:])), (s[0], SAMPLE),
                                   dtype=np.int32)
         else:
@@ -96,7 +99,7 @@ def take_sample(tree: dict, idx: dict) -> dict:
     import jax.numpy as jnp
     out = {}
     for k, a in tree.items():
-        if k.startswith("layers/"):
+        if stacked(k):
             out[k] = jnp.take_along_axis(a.reshape(a.shape[0], -1), idx[k],
                                          axis=1)
         else:

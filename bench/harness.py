@@ -15,8 +15,9 @@ on the trainer object, none of them in the timed path:
   of the parameters' change after three updates; the wrapper removes
   itself after step 3.
 
-After the window the program's state is dropped and the float32
-reference (``refmodel.py``) follows the same three steps from the same
+After the window the program's state is dropped and the configuration's
+float32 reference (``cell.reference``; ``refmodel.py`` unless the
+configuration names another) follows the same three steps from the same
 weights and rows; ``compare.py`` turns both into the numbers compared.
 """
 from __future__ import annotations
@@ -32,6 +33,7 @@ import os
 import shutil
 import sys
 import time
+import typing
 
 import numpy as np
 
@@ -122,10 +124,33 @@ def build_trainer(cell: cells.Cell):
         "--comm-spec", r["comm_spec"], "--seq", str(cell.seq),
         "--batch", str(cell.batch), "--steps", str(OPT["total_steps"]),
         "--ckpt", "", "--no-resume"])
-    trainer = build(args, ArchConfig(**cell.arch))
+    trainer = build(args, program_config(ArchConfig, cell.arch))
     trainer.oc = OptConfig(**OPT)
     trainer.tc.total_steps = 1 << 40     # the window, not a count, ends it
     return trainer
+
+
+def program_config(cls, block: dict):
+    """The program's configuration object ``cls`` of ``block``: each nested
+    object becomes the dataclass that ``cls`` annotates its field with
+    (``ArchConfig.moe`` a ``MoeConfig``); a key ``cls`` lacks ends the run."""
+    hints = typing.get_type_hints(cls)
+    fields = {f.name for f in dataclasses.fields(cls)}
+    unknown = sorted(set(block) - fields)
+    if unknown:
+        raise SystemExit(f"the program's {cls.__name__} has no field "
+                         f"{', '.join(unknown)}")
+    out = {}
+    for k, v in block.items():
+        if isinstance(v, dict):
+            kinds = [t for t in typing.get_args(hints[k]) or (hints[k],)
+                     if dataclasses.is_dataclass(t)]
+            if not kinds:
+                raise SystemExit(f"the program's {cls.__name__}.{k} takes "
+                                 f"no nested block")
+            v = program_config(kinds[0], v)
+        out[k] = v
+    return cls(**out)
 
 
 def program_layout(trainer) -> dict:
@@ -154,7 +179,8 @@ class ProgramHooks:
         self.shapes = {n: tuple(a.shape) for n, (_, a) in
                        zip(self.names, flat)}
         pos = self.shapes.get("pos_embed", (0,))[0]
-        want = refmodel.shapes(cell.arch, pos)
+        ref = cell.reference
+        want = ref.shapes(cell.arch, pos)
         if want != self.shapes:
             raise SystemExit(f"the program's parameters do not map onto the "
                              f"reference's: {sorted(set(want.items()) ^ set(self.shapes.items()))[:6]}")
@@ -181,10 +207,10 @@ class ProgramHooks:
 
         def grad_readings(mu, idx):
             g = {k: v / (1.0 - b1) for k, v in named(mu).items()}
-            return refmodel.leaf_norms(g), compare.take_sample(g, idx)
+            return ref.leaf_norms(g), compare.take_sample(g, idx)
 
         def delta_norms(master, key):
-            return refmodel.leaf_norms(
+            return ref.leaf_norms(
                 {k: v - weights.draw(key, names, k, shapes[k]).astype(
                     jnp.float32) for k, v in named(master).items()})
 
@@ -324,7 +350,7 @@ def run_program(trainer, hooks: ProgramHooks, cell: cells.Cell, seed: int,
 # ---------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=None)
-def _ref_fns(arch_json: str, shapes_json: str, var: refmodel.Variant,
+def _ref_fns(ref, arch_json: str, shapes_json: str, var: refmodel.Variant,
              wire: refmodel.Wire, devices: tuple):
     import jax
     import jax.numpy as jnp
@@ -350,14 +376,13 @@ def _ref_fns(arch_json: str, shapes_json: str, var: refmodel.Variant,
                              for k, s in shapes.items()},
                     out_shardings=shard)
     def train(p, m, v, t, batch, idx):
-        p, m, v, lval, g = refmodel.train_step(p, m, v, t, batch, arch, OPT,
-                                               var, wire)
-        return p, m, v, lval, refmodel.leaf_norms(g), \
-            compare.take_sample(g, idx)
+        p, m, v, lval, g = ref.train_step(p, m, v, t, batch, arch, OPT, var,
+                                          wire)
+        return p, m, v, lval, ref.leaf_norms(g), compare.take_sample(g, idx)
 
     step = jax.jit(train, donate_argnums=(0, 1, 2),
                    out_shardings=(shard, shard, shard, None, None, None))
-    delta = jax.jit(lambda a, b: refmodel.leaf_norms(
+    delta = jax.jit(lambda a, b: ref.leaf_norms(
         {k: a[k] - b[k] for k in a}))
     return init, zeros, step, delta, NamedSharding(mesh, P())
 
@@ -369,10 +394,11 @@ def reference_readings(cell: cells.Cell, shapes: dict, seed: int, fd,
     steps 0..2; ``half`` leaves out the second half of every row."""
     import jax
     import jax.numpy as jnp
+    ref = cell.reference
     init, zeros, step, delta, repl = _ref_fns(
-        json.dumps(cell.arch, sort_keys=True),
+        ref, json.dumps(cell.arch, sort_keys=True),
         json.dumps(shapes, sort_keys=True), var,
-        refmodel.wire_of(cell.config, cell.tp), tuple(devices))
+        ref.wire_of(cell.config, cell.tp), tuple(devices))
     key = weights.base_key(seed)
     idx = jax.device_put(compare.sample_index(shapes, seed), repl)
     losses, grad, sample = [], None, None
@@ -410,6 +436,7 @@ class LayerRun:
     """What a per-layer metric's reader is given."""
     trace: object
     tokens_per_s: float | None
+    flops_per_token: float   # model FLOPs per token, from the reference
     arch: dict
     wire: dict | None    # the configuration's compressed wire, if any
     tp: int              # the program's chips per tensor-parallel group
@@ -532,7 +559,9 @@ def main(argv=None, *, require_chip: bool = True,
         import xtrace
         t0 = time.perf_counter()
         tr = xtrace.load(tracer.xplane(), kernels)
-        lr = LayerRun(trace=tr, tokens_per_s=tps, arch=cell.arch,
+        lr = LayerRun(trace=tr, tokens_per_s=tps,
+                      flops_per_token=cell.reference.flops_per_token(
+                          cell.arch, cell.seq), arch=cell.arch,
                       wire=cell.config["precision"].get("tp_wire"),
                       seq=cell.seq, batch=cell.batch, chips=len(devices),
                       peaks=peaks, **layout)

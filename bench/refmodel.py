@@ -30,6 +30,27 @@ operands to float8 (e4m3) with a per-tensor scale; ``no_exchange`` keeps,
 of every scatter, only the first chip's partial sum (the exchange between
 chips left out); ``double`` moves one leaf twice as far as the update
 says.
+
+A configuration names its reference: ``"reference": "<module>"`` in its
+file, a module beside ``refmodel.py`` (for the benchmark's own tests,
+beside their ``BENCHMARK.json``); without the key it is this module.  A
+reference module provides
+
+* ``shapes(arch, pos_rows) -> {leaf: shape}``: the leaves the program's
+  parameters must map onto (``weights.program_name``), a stacked layer
+  segment's leaves under ``layers/`` (the first segment) or
+  ``layers<i>/`` (segment ``i``);
+* ``train_step(p, m, v, t, batch, arch, opt, var, wire)``: one AdamW step
+  in float32, returning ``(p, m, v, loss, clipped gradient)``;
+* ``flops_per_token(arch, seq)``: the model FLOPs per trained token that
+  ``mfu`` divides by (recomputed work left out);
+* ``FAULT_LEAF``: the leaf that the ``double`` fault moves twice;
+
+and, imported from here rather than copied, ``Variant``, ``SOUND``,
+``Wire``, ``wire_of`` and ``leaf_norms``.  The blocks of this module
+(``embed``, ``attention_block``, ``mlp_block``, ``head_loss``,
+``update``, ``ein``, ``norm``, ``layer_leaves``) are there to be reused
+by a reference whose layers differ in one part.
 """
 from __future__ import annotations
 
@@ -40,6 +61,8 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from weights import stacked
 
 HIGHEST = jax.lax.Precision.HIGHEST
 NEG_INF = -1e30
@@ -55,6 +78,7 @@ class Variant:
 
 
 SOUND = Variant()
+FAULT_LEAF = "layers/mlp/w1"   # the leaf the ``double`` fault moves
 
 
 @dataclasses.dataclass(frozen=True)
@@ -211,13 +235,13 @@ def _qdq(x):
     return x + jax.lax.stop_gradient(q - x)
 
 
-def _ein(spec, a, b, var: Variant):
+def ein(spec, a, b, var: Variant):
     if var.fp8:
         a, b = _qdq(a), _qdq(b)
     return jnp.einsum(spec, a, b, precision=HIGHEST)
 
 
-def _norm(x, p, prefix, arch):
+def norm(x, p, prefix, arch):
     eps = arch["norm_eps"]
     if arch["norm"] == "layernorm":
         mu = jnp.mean(x, -1, keepdims=True)
@@ -274,14 +298,15 @@ def _heads(x, n, hd):
         b, s, n, hd)
 
 
-def _layer(x, lp, arch, var: Variant, wire: Wire, block: int):
+def attention_block(x, lp, arch, var: Variant, wire: Wire, block: int):
+    """``x`` plus the layer's attention (``lp``: one layer's leaves)."""
     b, s, _ = x.shape
     tp = wire.tp
     h, kv, hd = arch["n_heads"], arch["n_kv_heads"], arch["head_dim"]
-    y = _gather(_norm(x, lp, "norm1", arch), wire)       # (tp, B, S, d)
-    q = _ein("tbsd,tdc->tbsc", y, _cols(lp["attn/wq"], tp), var)
-    k = _ein("tbsd,tdc->tbsc", y, _cols(lp["attn/wk"], tp), var)
-    v = _ein("tbsd,tdc->tbsc", y, _cols(lp["attn/wv"], tp), var)
+    y = _gather(norm(x, lp, "norm1", arch), wire)       # (tp, B, S, d)
+    q = ein("tbsd,tdc->tbsc", y, _cols(lp["attn/wq"], tp), var)
+    k = ein("tbsd,tdc->tbsc", y, _cols(lp["attn/wk"], tp), var)
+    v = ein("tbsd,tdc->tbsc", y, _cols(lp["attn/wv"], tp), var)
     if arch["qkv_bias"]:
         q = q + _vec(lp["attn/bq"], tp)
         k = k + _vec(lp["attn/bk"], tp)
@@ -294,30 +319,31 @@ def _layer(x, lp, arch, var: Variant, wire: Wire, block: int):
     v = jnp.repeat(v, group, axis=2)
     o = _attention(q, k, v, var, block)                 # (B, S, h*hd)
     o = jnp.moveaxis(o.reshape(b, s, tp, -1), 2, 0)     # each chip's heads
-    x = x + _exchange(_ein("tbsc,tcd->tbsd", o, _rows(lp["attn/wo"], tp),
-                           var), wire, var)
+    return x + _exchange(ein("tbsc,tcd->tbsd", o, _rows(lp["attn/wo"], tp),
+                             var), wire, var)
 
-    y = _gather(_norm(x, lp, "norm2", arch), wire)
+
+def mlp_block(x, lp, arch, var: Variant, wire: Wire):
+    """``x`` plus the layer's dense MLP."""
+    tp = wire.tp
+    y = _gather(norm(x, lp, "norm2", arch), wire)
     if arch["mlp"] == "swiglu":
-        a = jax.nn.silu(_ein("tbsd,tdf->tbsf", y, _cols(lp["mlp/w1"], tp),
-                             var)) \
-            * _ein("tbsd,tdf->tbsf", y, _cols(lp["mlp/w3"], tp), var)
+        a = jax.nn.silu(ein("tbsd,tdf->tbsf", y, _cols(lp["mlp/w1"], tp),
+                            var)) \
+            * ein("tbsd,tdf->tbsf", y, _cols(lp["mlp/w3"], tp), var)
     else:
-        a = jax.nn.gelu(_ein("tbsd,tdf->tbsf", y, _cols(lp["mlp/w1"], tp),
-                             var) + _vec(lp["mlp/b1"], tp),
+        a = jax.nn.gelu(ein("tbsd,tdf->tbsf", y, _cols(lp["mlp/w1"], tp),
+                            var) + _vec(lp["mlp/b1"], tp),
                         approximate=True)
-    x = x + _exchange(_ein("tbsf,tfd->tbsd", a, _rows(lp["mlp/w2"], tp),
-                           var), wire, var)
+    x = x + _exchange(ein("tbsf,tfd->tbsd", a, _rows(lp["mlp/w2"], tp),
+                          var), wire, var)
     if arch["mlp"] != "swiglu":
         x = x + lp["mlp/b2"]
     return x
 
 
-def loss(p: dict, batch: dict, arch: dict, var: Variant = SOUND,
-         wire: Wire = Wire(), block: int = 512):
-    """Mean next-token cross-entropy over the mask."""
-    tokens, labels, mask = batch["tokens"], batch["labels"], batch["mask"]
-    b, s = tokens.shape
+def embed(p: dict, tokens, arch: dict, var: Variant, wire: Wire):
+    """The residual stream's input (B, S, d): token rows and positions."""
     tp = wire.tp
     if arch["n_heads"] % tp or arch["n_kv_heads"] % tp:
         raise ValueError("the heads do not split over the chips")
@@ -328,16 +354,23 @@ def loss(p: dict, batch: dict, arch: dict, var: Variant = SOUND,
     x = _exchange(jnp.where(mine[..., None], table[tokens][None], 0.0),
                   wire, var)
     if arch["pos"] == "learned":
-        x = x + p["pos_embed"][:s][None]
-    layers = {k[len("layers/"):]: w for k, w in p.items()
-              if k.startswith("layers/")}
+        x = x + p["pos_embed"][:tokens.shape[1]][None]
+    return x
 
-    @jax.checkpoint
-    def body(x, lp):
-        return _layer(x, lp, arch, var, wire, block), None
 
-    x, _ = jax.lax.scan(body, x, layers)
-    x = _gather(_norm(x, p, "final_norm", arch), wire)  # (tp, B, S, d)
+def layer_leaves(p: dict, prefix: str = "layers/") -> dict:
+    """The stacked leaves under ``prefix``, named within one layer."""
+    return {k[len(prefix):]: w for k, w in p.items() if k.startswith(prefix)}
+
+
+def head_loss(x, p: dict, batch: dict, arch: dict, var: Variant,
+              wire: Wire, block: int):
+    """Mean next-token cross-entropy over the mask of the last layer's
+    output ``x``: final norm, output head, in blocks of rows."""
+    labels, mask = batch["labels"], batch["mask"]
+    b, s = labels.shape
+    tp = wire.tp
+    x = _gather(norm(x, p, "final_norm", arch), wire)  # (tp, B, S, d)
     head = p["embed/table"] if arch["tie_embeddings"] else p["head/table"]
     head = head.reshape(tp, -1, head.shape[-1])         # each chip's vocab
 
@@ -349,7 +382,7 @@ def loss(p: dict, batch: dict, arch: dict, var: Variant = SOUND,
     @jax.checkpoint
     def xent(carry, inp):
         xc, yc, mc = inp
-        logits = _ein("tbrd,tvd->brtv", xc, head, var)
+        logits = ein("tbrd,tvd->brtv", xc, head, var)
         logits = logits.reshape(logits.shape[:2] + (-1,))
         nll = jax.nn.logsumexp(logits, -1) \
             - jnp.take_along_axis(logits, yc[..., None], -1)[..., 0]
@@ -357,6 +390,33 @@ def loss(p: dict, batch: dict, arch: dict, var: Variant = SOUND,
 
     total, _ = jax.lax.scan(xent, jnp.zeros((), jnp.float32), (xs, ys, ms))
     return total / jnp.maximum(jnp.sum(mask), 1.0)
+
+
+def loss(p: dict, batch: dict, arch: dict, var: Variant = SOUND,
+         wire: Wire = Wire(), block: int = 512):
+    """Mean next-token cross-entropy over the mask."""
+    x = embed(p, batch["tokens"], arch, var, wire)
+
+    @jax.checkpoint
+    def body(x, lp):
+        x = attention_block(x, lp, arch, var, wire, block)
+        return mlp_block(x, lp, arch, var, wire), None
+
+    x, _ = jax.lax.scan(body, x, layer_leaves(p))
+    return head_loss(x, p, batch, arch, var, wire, block)
+
+
+def flops_per_token(arch: dict, seq: int) -> float:
+    """Model FLOPs per token, 6*N + 12*n_layers*(n_heads*head_dim)*seq
+    (PaLM, appendix B): N counts the weight-matrix parameters a token
+    multiplies, output head included and the embedding lookup left out."""
+    d, f, v = arch["d_model"], arch["d_ff"], arch["vocab_size"]
+    q = arch["n_heads"] * arch["head_dim"]
+    kv = arch["n_kv_heads"] * arch["head_dim"]
+    mlp = (3 if arch["mlp"] == "swiglu" else 2) * d * f
+    matrix_params = arch["n_layers"] * (2 * d * q + 2 * d * kv + mlp) + v * d
+    attn = 12 * arch["n_layers"] * arch["n_heads"] * arch["head_dim"] * seq
+    return 6.0 * matrix_params + attn
 
 
 # ---------------------------------------------------------------------------
@@ -376,22 +436,20 @@ def learning_rate(t, opt: dict):
 
 
 def leaf_norms(tree: dict) -> dict:
-    """Per-leaf L2 norms; a stacked ``layers/`` leaf gives one per layer."""
+    """Per-leaf L2 norms; a stacked layer leaf gives one per layer."""
     out = {}
     for k, a in tree.items():
-        if k.startswith("layers/"):
+        if stacked(k):
             out[k] = jnp.sqrt(jnp.sum(a * a, axis=tuple(range(1, a.ndim))))
         else:
             out[k] = jnp.sqrt(jnp.sum(a * a))
     return out
 
 
-def train_step(p, m, v, t, batch, arch: dict, opt: dict,
-               var: Variant = SOUND, wire: Wire = Wire()):
-    """One AdamW step at update count ``t`` (0 for the first).  Returns
-    the new (p, m, v), the loss, and the clipped gradient the update
-    used (call under jit, reducing it there)."""
-    lval, g = jax.value_and_grad(loss)(p, batch, arch, var, wire)
+def update(p, m, v, t, g, opt: dict, var: Variant = SOUND):
+    """AdamW at update count ``t`` (0 for the first) with the gradient
+    ``g`` clipped to ``clip_norm``.  Returns the new (p, m, v) and the
+    clipped gradient."""
     gnorm = jnp.sqrt(sum(jnp.sum(x * x) for x in g.values()))
     clip = jnp.minimum(1.0, opt["clip_norm"] / jnp.maximum(gnorm, 1e-12))
     g = {k: x * clip for k, x in g.items()}
@@ -407,4 +465,14 @@ def train_step(p, m, v, t, batch, arch: dict, opt: dict,
         upd = (nm[k] / bc1) / (jnp.sqrt(nv[k] / bc2) + opt["eps"])
         step = lr * (upd + opt["weight_decay"] * p[k])
         np_[k] = p[k] - (2.0 * step if k == var.double else step)
-    return np_, nm, nv, lval, g
+    return np_, nm, nv, g
+
+
+def train_step(p, m, v, t, batch, arch: dict, opt: dict,
+               var: Variant = SOUND, wire: Wire = Wire()):
+    """One AdamW step at update count ``t`` (0 for the first).  Returns
+    the new (p, m, v), the loss, and the clipped gradient the update
+    used (call under jit, reducing it there)."""
+    lval, g = jax.value_and_grad(loss)(p, batch, arch, var, wire)
+    p, m, v, g = update(p, m, v, t, g, opt, var)
+    return p, m, v, lval, g

@@ -16,7 +16,8 @@ import harness
 DATA = os.path.join(os.path.dirname(__file__), "data", "BENCHMARK.json")
 
 
-@pytest.mark.parametrize("workload", ["tiny.1dev.plain", "tiny.tp4.taco"])
+@pytest.mark.parametrize("workload", ["tiny.1dev.plain", "tiny.tp4.taco",
+                                      "tiny.1dev.moe"])
 def test_control_exceeds_a_limit_and_the_program_does_not(workload):
     seed = 3_000_000_023
     cell = cells.load(workload, DATA)

@@ -8,7 +8,9 @@ The faults are planted in the program at run time, never in its files:
 * ``state_unchanged``: the optimizer returns its state as it came;
 * ``half_batch``: half of the batch (rows, or tokens where the batch is
   one row) left out, the mean taken over the rest;
-* ``answer_altered``: one leaf's update applied twice;
+* ``answer_altered``: one leaf's update applied twice: the program's leaf
+  that the cell's reference names ``FAULT_LEAF`` (``segments/0/mlp/w1``
+  in a dense cell, ``segments/0/moe/w1`` in the sparse-expert one);
 * ``no_exchange``: the tensor-parallel reduce-scatter takes the local
   slice of its own partial sum (cells of several chips only).
 
@@ -22,10 +24,12 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+import cells
 import harness
+import weights
 
 DATA = os.path.join(os.path.dirname(__file__), "data", "BENCHMARK.json")
-CELLS = ["tiny.1dev.plain", "tiny.tp4.taco"]
+CELLS = ["tiny.1dev.plain", "tiny.tp4.taco", "tiny.1dev.moe"]
 SEED = 3_000_000_019
 
 
@@ -35,11 +39,14 @@ def run(workload):
                         require_chip=False, benchmark=DATA)
 
 
-def _master_path_of_w1(tree):
-    return tree["segments"][0]["mlp"]["w1"]
+def _at(leaf, fn, *trees):
+    """The last tree with ``fn`` applied to the leaf named ``leaf``."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, *a: fn(*a) if weights.program_name(path) == leaf
+        else a[-1], *trees)
 
 
-def plant(fault, monkeypatch):
+def plant(fault, monkeypatch, leaf=None):
     from repro.core import collectives
     from repro.models import transformer
     from repro.optim import adamw
@@ -53,12 +60,11 @@ def plant(fault, monkeypatch):
                 old = jax.tree_util.tree_map(
                     lambda m: m.astype(jnp.bfloat16), opt_state["master"])
                 return old, opt_state, metrics
-            was = _master_path_of_w1(opt_state["master"])
-            now = _master_path_of_w1(state["master"])
-            twice = was + 2.0 * (now - was)
-            state["master"]["segments"][0]["mlp"]["w1"] = twice
-            params["segments"][0]["mlp"]["w1"] = twice.astype(jnp.bfloat16)
-            return params, state, metrics
+            master = _at(leaf, lambda was, now: was + 2.0 * (now - was),
+                         opt_state["master"], state["master"])
+            params = _at(leaf, lambda m, p: m.astype(p.dtype), master,
+                         params)
+            return params, dict(state, master=master), metrics
 
         monkeypatch.setattr(adamw, "adamw_update", update)
     elif fault == "half_batch":
@@ -100,6 +106,7 @@ def test_sound_program_is_correct(workload):
     + [("tiny.tp4.taco", "no_exchange")])
 def test_fault_makes_the_run_incorrect(workload, fault, monkeypatch):
     monkeypatch.syspath_prepend(str(harness.cells.ROOT / "src"))
-    plant(fault, monkeypatch)
+    plant(fault, monkeypatch,
+          cells.load(workload, DATA).reference.FAULT_LEAF)
     out = run(workload)
     assert not out["correct"], (fault, out["checks"])

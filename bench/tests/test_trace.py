@@ -30,8 +30,9 @@ def trace():
 
 
 def layer_run(tr):
-    return LayerRun(trace=tr, tokens_per_s=None, arch={}, wire=None, tp=4,
-                    remat="full", seq=0, batch=0, chips=4, peaks={})
+    return LayerRun(trace=tr, tokens_per_s=None, flops_per_token=0.0,
+                    arch={}, wire=None, tp=4, remat="full", seq=0, batch=0,
+                    chips=4, peaks={})
 
 
 def test_kernel_names_come_from_the_compiled_module(trace):

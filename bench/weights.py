@@ -2,7 +2,8 @@
 reference alike.
 
 Every leaf is named as the reference names it (``layers/attn/wq`` for a
-stack of per-layer matrices, ``embed/table``, ...) and drawn from its own
+stack of per-layer matrices, ``layers1/...`` for a second segment of
+layers of another kind, ``embed/table``, ...) and drawn from its own
 key, ``fold_in(seed key, index of the name in sorted order)``, as
 N(0, 0.02) (learned positions N(0, 0.01)) and rounded to bfloat16, the
 type the program trains in.  Norm gains and biases are drawn too, so that
@@ -12,6 +13,8 @@ reference gets the same values widened to float32.
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 
@@ -19,18 +22,28 @@ from feed import seed_words
 
 SCALE = 0.02
 POS_SCALE = 0.01
+SEGMENT = re.compile(r"segments/(\d+)/")
+STACKED = re.compile(r"layers\d*/")
 
 
 def program_name(path) -> str:
     """Reference name of a leaf of the program's parameter tree: the
-    stacked layer segment ``segments/0/...`` is ``layers/...``."""
+    stacked layer segment ``segments/0/...`` is ``layers/...``, and a
+    later segment ``segments/<i>/...`` is ``layers<i>/...``."""
     parts = []
     for k in path:
         parts.append(str(getattr(k, "key", getattr(k, "idx", k))))
     name = "/".join(parts)
-    if name.startswith("segments/0/"):
-        return "layers/" + name[len("segments/0/"):]
+    seg = SEGMENT.match(name)
+    if seg:
+        i = seg.group(1)
+        return f"layers{'' if i == '0' else i}/" + name[seg.end():]
     return name
+
+
+def stacked(name: str) -> bool:
+    """Whether leaf ``name`` stacks one layer per row of its first axis."""
+    return STACKED.match(name) is not None
 
 
 def base_key(seed: int):
